@@ -55,7 +55,7 @@ from repro.core import (
     FedAvg, PROFILES, RoundSpec, init_collective_residual, make_round_step,
 )
 from repro.core.cost_model import CostModel
-from repro.launch.mesh import collective_tiers
+from repro.launch.mesh import collective_tiers, make_local_mesh
 from repro.models import build_model
 from repro.models.sharding import ShardRules, shard_client_state
 from repro.optim import sgd
@@ -79,7 +79,7 @@ def _mesh():
             "mesh_bench needs 8 devices (XLA_FLAGS="
             "--xla_force_host_platform_device_count=8 before jax imports)"
         )
-    return jax.make_mesh((2, 4), AXES)
+    return make_local_mesh(pod=2, data=4)
 
 
 def _setup(seed=0):
@@ -190,7 +190,7 @@ def main() -> None:
 
     # fsdp-style state sharding is orthogonal to the collective axes: use a
     # pure fsdp mesh over the same 8 devices for the memory story
-    fsdp_mesh = jax.make_mesh((4, 2), ("data", "model"))
+    fsdp_mesh = make_local_mesh(data=4, model=2)
     memory = sharded_state_memory(fsdp_mesh)
     print(
         f"mesh[sharded_state],0,per_device_bytes={memory['per_device_bytes']};"

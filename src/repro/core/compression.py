@@ -625,17 +625,14 @@ class NullCodec(UpdateCodec):
     def aggregate_updates(self, client_params, global_params, weights, state):
         """Leafwise fp32 weighted mean — the fp32 wire loses nothing, so the
         uncompressed path never flattens the model into one (C, N) matrix
-        (same reasoning as the identity ``transmit_tree``)."""
-        wf = weights.astype(jnp.float32)
-        wsum = safe_weight_sum(wf)
+        (same reasoning as the identity ``transmit_tree``).  Each leaf's
+        (C, leaf size) delta block goes through the fedavg reduce kernel."""
 
         def leaf_mean(xs, g):
-            wshape = (xs.shape[0],) + (1,) * (xs.ndim - 1)
             gf = g.astype(jnp.float32)
-            acc = jnp.sum(
-                (xs.astype(jnp.float32) - gf) * wf.reshape(wshape), axis=0
-            )
-            return (gf + acc / wsum).astype(g.dtype)
+            delta = xs.astype(jnp.float32).reshape(xs.shape[0], -1) - gf.reshape(-1)
+            mean = ops.fedavg_reduce(delta, weights)
+            return (gf + mean.reshape(g.shape)).astype(g.dtype)
 
         # state passes through unchanged (() flat; a tuple of ()s segmented)
         # so the scan carry keeps one stable structure across rounds
@@ -704,27 +701,18 @@ class Int8Codec(UpdateCodec):
     def encode_batch(self, deltas: jnp.ndarray):
         """(C, N) -> q (C, Np) int8 + scales (C, Np/block); Np = padded N.
 
-        Rows are padded to a block multiple, so flattening (C, Np) keeps
-        every quantization block inside one client row and the 1-D Pallas
-        kernel applies unchanged.
+        Rows are padded to a block multiple, so every quantization block
+        stays inside one client row; the kernel quantizes the (C, Np)
+        matrix in its own layout.
         """
-        c, n = deltas.shape
-        pad = (-n) % self.block
-        padded = jnp.pad(deltas, ((0, 0), (0, pad)))
-        np_ = n + pad
-        q, scale = ops.quantize_int8(padded.reshape(-1), block=self.block)
-        return {
-            "q": q.reshape(c, np_),
-            "scale": scale.reshape(c, np_ // self.block),
-            "n": n,
-        }
+        n = deltas.shape[1]
+        padded = jnp.pad(deltas, ((0, 0), (0, (-n) % self.block)))
+        q, scale = ops.quantize_int8(padded, block=self.block)
+        return {"q": q, "scale": scale, "n": n}
 
     def decode_batch(self, enc) -> jnp.ndarray:
-        c = enc["q"].shape[0]
-        vec = ops.dequantize_int8(
-            enc["q"].reshape(-1), enc["scale"].reshape(-1), block=self.block
-        )
-        return vec.reshape(c, -1)[:, : enc["n"]]
+        vec = ops.dequantize_int8(enc["q"], enc["scale"], block=self.block)
+        return vec[:, : enc["n"]]
 
     def reduce(self, enc, weights: jnp.ndarray, *, interpret: bool = False):
         """Weighted-mean decode straight off the int8 payload (fused kernel)."""

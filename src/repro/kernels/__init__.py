@@ -1,8 +1,10 @@
 """Pallas TPU kernels for the framework's compute hot-spots.
 
 Each kernel has: <name>.py (pl.pallas_call + BlockSpec), an entry in ops.py
-(backend-dispatching jit wrapper) and an oracle in ref.py (pure jnp).  On
-this CPU container kernels are validated with interpret=True.
+(backend-dispatching jit wrapper) and an oracle in ref.py (pure jnp).  On a
+TPU the dispatchers run the kernels; on the CPU the tests check the kernel
+bodies against the oracles with interpret=True, and
+tests/test_chip_compile.py compiles them for a described TPU v5e.
 
 Submodules load lazily (PEP 562): importing ``repro.kernels`` must not pull
 in jax — fedlint's import-scan gate (and pytest collection on machines
@@ -13,9 +15,9 @@ from __future__ import annotations
 import importlib
 
 _SUBMODULES = (
-    "decode_attention", "dequant_reduce", "fedavg_reduce",
-    "flash_attention", "ops", "quantize", "ref", "scatter_reduce",
-    "selective_scan",
+    "collective_quant", "decode_attention", "dequant_reduce",
+    "fedavg_reduce", "flash_attention", "ops", "quantize", "ref",
+    "scatter_reduce", "selective_scan",
 )
 
 

@@ -18,8 +18,9 @@ values into an int32 container — the psum accumulator dtype; the values
 themselves fit int8 (|q| <= 127, the wire carries one byte per element),
 and the int32 sum cannot overflow below a 2**31/127 ~= 16.9M-device fan-in.
 
-Grid = (N/bn,); each step packs/unpacks a bn tile (bn % 256 == 0) in one
-HBM pass, same streaming shape as the uplink quantizer.
+Both are ``quantize.scaled_map`` kernels: one HBM pass over the lane-dense
+(N,) operand and its (N/block,) scales, with the same tiling, ceil-division
+grid and pad as the uplink quantizer (``quantize.py``).
 """
 from __future__ import annotations
 
@@ -27,69 +28,27 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-BLOCK = 256
-
-# Static VMEM ceiling audited by fedlint (pallas-vmem-budget), in
-# fp32-equivalent elements (int32 tiles cost the same): 128K elems = 512 KB
-# — thin streaming kernels, far below the ~16 MB/core.
-VMEM_BUDGET_ELEMS = 1 << 17
-VMEM_ASSUMES = {"n": 1 << 22}
+from .quantize import BLOCK, LANES, dequant, scaled_map
 
 
-def _pack_kernel(x_ref, s_ref, q_ref, *, block: int):
-    x = x_ref[...].astype(jnp.float32).reshape(-1, block)
-    s = s_ref[...].astype(jnp.float32)
-    q = jnp.clip(jnp.round(x / s[:, None]), -127, 127)
-    q_ref[...] = q.reshape(-1).astype(jnp.int32)
+def _pack(x, s):
+    return jnp.clip(jnp.round(x.astype(jnp.float32) / s), -127, 127)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "bn", "interpret"))
-def collective_pack(x, scales, *, block: int = BLOCK, bn: int = 8192,
+def collective_pack(x, scales, *, block: int = BLOCK, bn: int = LANES * BLOCK,
                     interpret: bool = False):
     """x: (N,) fp32, scales: (N/block,) fp32 (shared, pre-pmax'd) ->
     q int32 (N,) with every value in [-127, 127].  N % block == 0."""
-    n = x.shape[0]
-    bn = min(bn, n)
-    assert n % block == 0 and bn % block == 0
-    kernel = functools.partial(_pack_kernel, block=block)
-    return pl.pallas_call(
-        kernel,
-        grid=(n // bn,),
-        in_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn // block,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
-        interpret=interpret,
-    )(x, scales)
-
-
-def _unpack_kernel(q_ref, s_ref, x_ref, *, block: int):
-    q = q_ref[...].astype(jnp.float32).reshape(-1, block)
-    s = s_ref[...].astype(jnp.float32)
-    x_ref[...] = (q * s[:, None]).reshape(-1)
+    return scaled_map(_pack, x, scales, jnp.int32, block=block, bn=bn,
+                      interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "bn", "interpret"))
-def collective_unpack(q, scales, *, block: int = BLOCK, bn: int = 8192,
+def collective_unpack(q, scales, *, block: int = BLOCK, bn: int = LANES * BLOCK,
                       interpret: bool = False):
     """q: (N,) int32 (one device's pack, or the psum of many), scales as in
     ``collective_pack`` -> fp32 (N,): the fused post-psum dequant."""
-    n = q.shape[0]
-    bn = min(bn, n)
-    assert n % block == 0 and bn % block == 0
-    kernel = functools.partial(_unpack_kernel, block=block)
-    return pl.pallas_call(
-        kernel,
-        grid=(n // bn,),
-        in_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn // block,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
-        interpret=interpret,
-    )(q, scales)
+    return scaled_map(dequant, q, scales, jnp.float32, block=block, bn=bn,
+                      interpret=interpret)

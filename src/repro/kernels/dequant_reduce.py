@@ -5,12 +5,15 @@ Input is the int8 wire payload of every client: q (C, N) int8 values and
 per-256-block fp32 scales (C, N/block).  The unfused reduce materializes
 the dequantized fp32 (C, N) matrix in HBM (4x the int8 payload) and then
 reads it back for the weighted reduce — three HBM passes over C x N.  This
-kernel makes ONE pass: each grid step loads a (C, bn) int8 tile plus its
-scales, dequantizes in VMEM, and contracts against the normalized weight
-vector on the MXU (1xC @ Cxbn, fp32 accumulate).  HBM traffic of the
-reduce is the int8 payload + scales + the (N,) result — the bandwidth
-roofline for this op.  (The error-feedback residual in core/rounds.py
-still dequantizes the payload separately, once per round.)
+kernel makes ONE pass.  It keeps the payload's own lane-dense (C, N)
+layout and the tiling of ``quantize.py``: the grid is (column tiles,
+client tiles); each step loads a (cb, tn) int8 tile plus its (cb, tn/block)
+scales, dequantizes it block by block in VMEM, and adds the weighted sum of
+its cb client rows into the (1, tn) fp32 output tile, which stays resident
+across the client axis.  HBM traffic of the reduce is the int8 payload +
+scales + the (N,) result — the bandwidth roofline for this op.  (The
+error-feedback residual in core/rounds.py still dequantizes the payload
+separately, once per round.)
 """
 from __future__ import annotations
 
@@ -22,68 +25,61 @@ from jax.experimental import pallas as pl
 
 from repro.utils.pytree import safe_weight_sum
 
-BLOCK = 256
+from .quantize import BLOCK, LANES, block_scale, pad2, tiling
 
 # Static VMEM ceiling audited by fedlint (pallas-vmem-budget), in
 # fp32-equivalent elements (the int8 tile is costed at fp32 — the kernel
 # dequantizes it in VMEM anyway): 3M elems = 12 MB of ~16 MB/core.
 VMEM_BUDGET_ELEMS = 3 * (1 << 20)
-# Worst-case audited dims; the bn clamp below enforces the budget at
-# runtime for any cohort up to this C.
-VMEM_ASSUMES = {"c": 1024, "n": 1 << 22}
+# Worst-case tile: ROW_TILE clients x one column tile of LANES blocks.
+VMEM_ASSUMES = {"mb": 32, "tn": 128 * 256, "block": 256}
 
 
-def _dequant_reduce_kernel(q_ref, s_ref, w_ref, o_ref, *, block: int):
-    q = q_ref[...].astype(jnp.float32)              # (C, bn)
-    s = s_ref[...].astype(jnp.float32)              # (C, bn/block)
-    w = w_ref[...].astype(jnp.float32)              # (1, C) normalized weights
-    c, bn = q.shape
-    x = (q.reshape(c, bn // block, block) * s[:, :, None]).reshape(c, bn)
-    acc = jax.lax.dot_general(
-        w, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (1, bn)
-    o_ref[...] = acc[0].astype(o_ref.dtype)
+def _dequant_reduce_kernel(w_ref, q_ref, s_ref, o_ref, *, block: int):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    w = w_ref[...]                                      # (cb, 1) weights
+    s = s_ref[...]                                      # (cb, tn/block)
+    for g in range(q_ref.shape[1] // block):
+        cols = slice(g * block, (g + 1) * block)
+        x = q_ref[:, cols].astype(jnp.float32) * block_scale(s, g)
+        o_ref[:, cols] += jnp.sum(w * x, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "bn", "interpret"))
 def dequant_reduce(
-    q, scales, weights, *, block: int = BLOCK, bn: int = 8192, interpret: bool = False
+    q, scales, weights, *, block: int = BLOCK, bn: int = LANES * BLOCK,
+    interpret: bool = False,
 ):
     """(C,N) int8 x (C,N/block) fp32 x (C,) -> (N,) fp32 weighted mean.
 
-    N % block == 0 (the encoder pads).  N is further padded up to a multiple
-    of the tile width bn with zero blocks (zero scale -> zero contribution)
-    and the pad is sliced off the result.  Weights are auto-normalized.
+    N % block == 0 (the encoder pads).  Clients and columns are padded up
+    to whole tiles with zero payload and zero weight (no contribution);
+    the pad is sliced off the result.  Weights are auto-normalized.
     """
     c, n = q.shape
     assert n % block == 0, f"N={n} not a multiple of block={block}"
     assert scales.shape == (c, n // block), scales.shape
-    bn = min(bn, n)
-    bn = max(block, (bn // block) * block)
-    # large-cohort clamp: double-buffered (C, bn) payload + (C, bn/block)
-    # scales + the (1, C) weight row + the (bn,) output tile must fit the
-    # budget: 2*C*bn + 2*C*bn/block + C + 2*bn <= VMEM_BUDGET_ELEMS
-    bn = max(block, min(
-        bn, (VMEM_BUDGET_ELEMS - c) // (2 * c + 2 * c // block + 2)
-    ) // block * block)
-    pad = (-n) % bn
-    if pad:
-        q = jnp.pad(q, ((0, 0), (0, pad)))
-        scales = jnp.pad(scales, ((0, 0), (0, pad // block)))
-    np_ = n + pad
+    mb, mp, tn, np_ = tiling(c, n, bn, block)
     wf = weights.astype(jnp.float32)
-    wn = (wf / safe_weight_sum(wf)).reshape(1, c)
+    wn = (wf / safe_weight_sum(wf)).reshape(c, 1)
 
     out = pl.pallas_call(
         functools.partial(_dequant_reduce_kernel, block=block),
-        grid=(np_ // bn,),
+        grid=(np_ // tn, mp // mb),
         in_specs=[
-            pl.BlockSpec((c, bn), lambda i: (0, i)),
-            pl.BlockSpec((c, bn // block), lambda i: (0, i)),
-            pl.BlockSpec((1, c), lambda i: (0, 0)),
+            pl.BlockSpec((mb, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((mb, tn), lambda i, j: (j, i)),
+            pl.BlockSpec((mb, tn // block), lambda i, j: (j, i)),
         ],
-        out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((np_,), jnp.float32),
+        out_specs=pl.BlockSpec((1, tn), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, np_), jnp.float32),
         interpret=interpret,
-    )(q, scales, wn)
-    return out[:n] if pad else out
+    )(
+        pad2(wn, mp, 1),
+        pad2(q, mp, np_),
+        pad2(scales.astype(jnp.float32), mp, np_ // block),
+    )
+    return out[0, :n]

@@ -1,9 +1,10 @@
 """FedAvg weighted aggregation (Pallas TPU) — the server's compute hotspot.
 
 updates: (C, N) flat client updates, weights: (C,).  Grid = (N/bn,): each
-step loads a (C, bn) tile and contracts against the weight vector on the MXU
-(1xC @ Cxbn), fp32 accumulate — one pass over the C x N payload at HBM
-bandwidth, which is the roofline for this op.
+step loads a (C, bn) tile and sums its rows against the (C, 1) weight
+column on the VPU into a lane-dense (1, bn) output tile, in fp32 — one
+pass over the C x N payload at HBM bandwidth, which is the roofline for
+this op.
 """
 from __future__ import annotations
 
@@ -26,11 +27,8 @@ VMEM_ASSUMES = {"c": 1024, "n": 1 << 22}
 
 def _reduce_kernel(u_ref, w_ref, o_ref):
     u = u_ref[...].astype(jnp.float32)          # (C, bn)
-    w = w_ref[...].astype(jnp.float32)          # (1, C) normalized weights
-    acc = jax.lax.dot_general(
-        w, u, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (1, bn)
-    o_ref[...] = acc[0].astype(o_ref.dtype)
+    w = w_ref[...]                              # (C, 1) normalized weights
+    o_ref[...] = jnp.sum(u * w, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
@@ -43,25 +41,25 @@ def fedavg_reduce(updates, weights, *, bn: int = 8192, interpret: bool = False):
     c, n = updates.shape
     bn = max(128, min(bn, n) // 128 * 128)  # lane-aligned tile width
     # shrink the tile for large cohorts so the double-buffered (C, bn)
-    # update tiles + the (1, C) weight row + the (bn,) output stay inside
-    # the declared VMEM budget: 2*C*bn + 2*bn + C <= VMEM_BUDGET_ELEMS
+    # update tiles + the (C, 1) weight column + the (1, bn) output stay
+    # inside the declared VMEM budget: 2*C*bn + 2*bn + C <= VMEM_BUDGET_ELEMS
     bn = max(128, min(bn, (VMEM_BUDGET_ELEMS - c) // (2 * (c + 1))) // 128 * 128)
     pad = (-n) % bn
     if pad:
         updates = jnp.pad(updates, ((0, 0), (0, pad)))
     np_ = n + pad
     wf = weights.astype(jnp.float32)
-    wn = (wf / safe_weight_sum(wf)).reshape(1, c)
+    wn = (wf / safe_weight_sum(wf)).reshape(c, 1)
 
     out = pl.pallas_call(
         _reduce_kernel,
         grid=(np_ // bn,),
         in_specs=[
             pl.BlockSpec((c, bn), lambda i: (0, i)),
-            pl.BlockSpec((1, c), lambda i: (0, 0)),
+            pl.BlockSpec((c, 1), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((np_,), updates.dtype),
+        out_specs=pl.BlockSpec((1, bn), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, np_), updates.dtype),
         interpret=interpret,
     )(updates, wn)
-    return out[:n] if pad else out
+    return out[0, :n]

@@ -1,11 +1,12 @@
 """Public kernel entry points with backend dispatch.
 
-Models call these; on TPU they route to the Pallas kernels, elsewhere to the
-pure-jnp oracles in ref.py (which is also what the CPU dry-run lowers).
-``set_impl`` lets tests force either path (the ``REPRO_KERNEL_IMPL`` env
-var sets the same switch at import, which is how CI forces the Pallas
-bodies through interpret mode on its CPU runners), and ``interpret=True``
-runs the Pallas kernel bodies on CPU for the per-kernel allclose tests.
+Models and codecs call these.  On a TPU backend they run the Pallas
+kernels; on any other backend (the CPU the tests run on) they run the
+pure-jnp oracles in ref.py.  ``set_impl`` lets tests force either path
+(the ``REPRO_KERNEL_IMPL`` env var sets the same switch at import, which
+is how CI forces the Pallas bodies through interpret mode on its CPU
+runners), and ``interpret=True`` runs the Pallas kernel bodies on CPU for
+the per-kernel allclose tests.
 """
 from __future__ import annotations
 
@@ -112,18 +113,17 @@ def fedavg_reduce(updates, weights, *, interpret=False, normalize=True):
 
 def dequant_reduce(q, scales, weights, block: int = 256, *, interpret=False,
                    normalize=True):
-    """Fused server-side decode: int8 payload (C,N) + scales -> (N,) mean."""
+    """Fused server-side decode: int8 payload (C,N) + scales -> (N,) mean.
+    N % block == 0 (the encoder pads); the kernel tile-pads beyond."""
     if _use_pallas() or interpret:
         from .dequant_reduce import dequant_reduce as dr
 
-        # the encoder pads to a block multiple; the kernel tile-pads beyond
-        if q.shape[-1] % block == 0:
-            out = dr(
-                q, scales, weights, block=block,
-                interpret=interpret or jax.default_backend() != "tpu",
-            )
-            return out if normalize else _denormalize(out, weights)
-    out = ref.dequant_reduce(q, scales, weights, block=block)
+        out = dr(
+            q, scales, weights, block=block,
+            interpret=interpret or jax.default_backend() != "tpu",
+        )
+    else:
+        out = ref.dequant_reduce(q, scales, weights, block=block)
     return out if normalize else _denormalize(out, weights)
 
 
@@ -178,12 +178,13 @@ def topk_scatter_reduce(idx, val, weights, n_params: int, *, interpret=False,
 
 
 # ---------------- int8 codec ----------------
+# The int8 kernels take any (..., N) with N % block == 0 (the encoders pad
+# to a block multiple) and tile-pad beyond it themselves: no shape gate.
 def quantize_int8(x, block: int = 256, *, interpret=False):
     if _use_pallas() or interpret:
         from .quantize import quantize_int8 as qz
 
-        if x.shape[-1] % max(block, 1024) == 0:
-            return qz(x, block=block, interpret=interpret or jax.default_backend() != "tpu")
+        return qz(x, block=block, interpret=interpret or jax.default_backend() != "tpu")
     return ref.quantize_int8(x, block=block)
 
 
@@ -191,8 +192,7 @@ def dequantize_int8(q, scale, block: int = 256, *, interpret=False):
     if _use_pallas() or interpret:
         from .quantize import dequantize_int8 as dq
 
-        if q.shape[-1] % max(block, 1024) == 0:
-            return dq(q, scale, block=block, interpret=interpret or jax.default_backend() != "tpu")
+        return dq(q, scale, block=block, interpret=interpret or jax.default_backend() != "tpu")
     return ref.dequantize_int8(q, scale, block=block)
 
 
@@ -204,9 +204,8 @@ def collective_pack(x, scales, block: int = 256, *, interpret=False):
     if _use_pallas() or interpret:
         from .collective_quant import collective_pack as cp
 
-        if x.shape[-1] % max(block, 1024) == 0:
-            return cp(x, scales, block=block,
-                      interpret=interpret or jax.default_backend() != "tpu")
+        return cp(x, scales, block=block,
+                  interpret=interpret or jax.default_backend() != "tpu")
     return ref.collective_pack(x, scales, block=block)
 
 
@@ -215,7 +214,6 @@ def collective_unpack(q, scales, block: int = 256, *, interpret=False):
     if _use_pallas() or interpret:
         from .collective_quant import collective_unpack as cu
 
-        if q.shape[-1] % max(block, 1024) == 0:
-            return cu(q, scales, block=block,
-                      interpret=interpret or jax.default_backend() != "tpu")
+        return cu(q, scales, block=block,
+                  interpret=interpret or jax.default_backend() != "tpu")
     return ref.collective_unpack(q, scales, block=block)

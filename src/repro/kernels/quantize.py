@@ -1,8 +1,20 @@
 """int8 block quantization codec (Pallas TPU) for FL update compression.
 
-Symmetric per-256-block scaling.  Grid = (N/bn,); each step quantizes a bn
-tile (bn % 256 == 0): reshape to (bn/256, 256), rowwise absmax -> scale,
-round/clamp to int8.  Dequantize reverses it.
+Symmetric per-256-block scaling along the last axis.  Inputs are (M, N)
+(a 1-D (N,) vector is one row), kept in their own lane-dense layout: no
+relayout to a (blocks, 256) view, which on the TPU is a full HBM copy and
+a slow compile for int8 at round widths.  The grid is (row tiles, column
+tiles).  A column tile holds 128 scale blocks, so the scales of a tile are
+one lane-dense (rows, 128) tile; the kernel walks the tile's blocks with
+static 256-lane slices, and puts each block's scale in its lane through an
+iota mask.  An N that is no wider than one column tile is one full-width
+tile.  Rows and columns are padded up to whole tiles (ceil-division grid),
+so a tail that is not a tile multiple is processed too, and the pad is
+sliced off.  Dequantize reverses it.
+
+The scaled elementwise kernels of ``collective_quant.py`` and the fused
+reduce of ``dequant_reduce.py`` share this tiling (``tiling``) and the
+per-block scale extraction (``block_scale``).
 """
 from __future__ import annotations
 
@@ -13,67 +25,130 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BLOCK = 256
+LANES = 128     # scale blocks per column tile: one lane-dense scale tile
+ROW_TILE = 32   # int8 tiles are (32, 128) on the TPU
 
 # Static VMEM ceiling audited by fedlint (pallas-vmem-budget), in
-# fp32-equivalent elements (int8 tiles costed at fp32): 128K elems = 512 KB
-# — these are thin streaming kernels, far below the ~16 MB/core.
-VMEM_BUDGET_ELEMS = 1 << 17
-VMEM_ASSUMES = {"n": 1 << 22}
+# fp32-equivalent elements: 4.5M elems.  int8 tiles are costed at fp32
+# here, so the real worst case (a 32 x 32768 fp32 tile in, its int8 tile
+# out, both double-buffered) is about 10 MB of the 16 MB/core.
+VMEM_BUDGET_ELEMS = 9 * (1 << 19)
+# Worst-case tile: ROW_TILE rows x one column tile of LANES blocks.
+VMEM_ASSUMES = {"mb": ROW_TILE, "tn": LANES * BLOCK, "block": BLOCK}
+
+
+def tiling(m: int, n: int, bn: int, block: int) -> tuple[int, int, int, int]:
+    """(rows per tile, padded M, columns per tile, padded N) for an (M, N)
+    operand with ``block``-wide scale blocks; ``bn`` asks for a column tile
+    width, rounded to whole scale tiles of LANES blocks."""
+    mb = m if m <= ROW_TILE else ROW_TILE
+    unit = LANES * block
+    tn = n if n <= unit else max(unit, bn // unit * unit)
+    return mb, -(-m // mb) * mb, tn, -(-n // tn) * tn
+
+
+def pad2(a, rows: int, cols: int, value=0):
+    """Pad a 2-D array up to (rows, cols) with ``value``."""
+    pr, pc = rows - a.shape[0], cols - a.shape[1]
+    return jnp.pad(a, ((0, pr), (0, pc)), constant_values=value) if pr or pc else a
+
+
+def block_scale(s, g: int):
+    """Column ``g`` of a (rows, blocks) scale tile as a (rows, 1) column,
+    taken with an iota mask and a lane sum (exact: one lane is non-zero)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.sum(jnp.where(lane == g, s, 0.0), axis=1, keepdims=True)
+
+
+def _as_rows(a, block: int):
+    """(..., N) -> (M, N), with N % block checked."""
+    n = a.shape[-1]
+    assert n % block == 0, f"N={n} not a multiple of block={block}"
+    return a.reshape(-1, n)
 
 
 def _quant_kernel(x_ref, q_ref, s_ref, *, block: int):
-    x = x_ref[...].astype(jnp.float32)                  # (bn,)
-    xb = x.reshape(-1, block)
-    scale = jnp.max(jnp.abs(xb), axis=1) / 127.0
-    scale = jnp.where(scale == 0, 1.0, scale)
-    q = jnp.clip(jnp.round(xb / scale[:, None]), -127, 127)
-    q_ref[...] = q.reshape(-1).astype(jnp.int8)
-    s_ref[...] = scale.astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, s_ref.shape, 1)
+    s = jnp.zeros(s_ref.shape, jnp.float32)
+    for g in range(x_ref.shape[1] // block):
+        cols = slice(g * block, (g + 1) * block)
+        x = x_ref[:, cols].astype(jnp.float32)               # (rows, block)
+        scale = jnp.max(jnp.abs(x), axis=1, keepdims=True) / 127.0
+        scale = jnp.where(scale == 0, 1.0, scale)
+        q_ref[:, cols] = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
+        s = jnp.where(lane == g, scale, s)
+    s_ref[...] = s
 
 
 @functools.partial(jax.jit, static_argnames=("block", "bn", "interpret"))
-def quantize_int8(x, *, block: int = BLOCK, bn: int = 8192, interpret: bool = False):
-    """x: (N,) -> (q int8 (N,), scales fp32 (N/block,)). N % block == 0."""
-    n = x.shape[0]
-    bn = min(bn, n)
-    assert n % block == 0 and bn % block == 0
-    kernel = functools.partial(_quant_kernel, block=block)
+def quantize_int8(x, *, block: int = BLOCK, bn: int = LANES * BLOCK,
+                  interpret: bool = False):
+    """x: (..., N) -> (q int8 (..., N), scales fp32 (..., N/block)).
+    N % block == 0."""
+    x2 = _as_rows(x, block)
+    m, n = x2.shape
+    mb, mp, tn, np_ = tiling(m, n, bn, block)
     q, s = pl.pallas_call(
-        kernel,
-        grid=(n // bn,),
-        in_specs=[pl.BlockSpec((bn,), lambda i: (i,))],
+        functools.partial(_quant_kernel, block=block),
+        grid=(mp // mb, np_ // tn),
+        in_specs=[pl.BlockSpec((mb, tn), lambda i, j: (i, j))],
         out_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn // block,), lambda i: (i,)),
+            pl.BlockSpec((mb, tn), lambda i, j: (i, j)),
+            pl.BlockSpec((mb, tn // block), lambda i, j: (i, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int8),
-            jax.ShapeDtypeStruct((n // block,), jnp.float32),
+            jax.ShapeDtypeStruct((mp, np_), jnp.int8),
+            jax.ShapeDtypeStruct((mp, np_ // block), jnp.float32),
         ],
         interpret=interpret,
-    )(x)
-    return q, s
+    )(pad2(x2, mp, np_))
+    lead = x.shape[:-1]
+    return (
+        q[:m, :n].reshape(*lead, n),
+        s[:m, : n // block].reshape(*lead, n // block),
+    )
 
 
-def _dequant_kernel(q_ref, s_ref, x_ref, *, block: int):
-    q = q_ref[...].astype(jnp.float32).reshape(-1, block)
-    s = s_ref[...].astype(jnp.float32)
-    x_ref[...] = (q * s[:, None]).reshape(-1).astype(x_ref.dtype)
+def scaled_map(fn, a, scales, out_dtype, *, block: int, bn: int,
+               interpret: bool):
+    """out[..., j] = fn(a[..., j], scales[..., j // block]) over (..., N)
+    ``a`` and its (..., N/block) ``scales``, one HBM pass."""
+    a2 = _as_rows(a, block)
+    m, n = a2.shape
+    mb, mp, tn, np_ = tiling(m, n, bn, block)
+
+    def kernel(a_ref, s_ref, o_ref):
+        s = s_ref[...]
+        for g in range(a_ref.shape[1] // block):
+            cols = slice(g * block, (g + 1) * block)
+            o_ref[:, cols] = fn(a_ref[:, cols], block_scale(s, g)).astype(out_dtype)
+
+    out = pl.pallas_call(
+        kernel,
+        grid=(mp // mb, np_ // tn),
+        in_specs=[
+            pl.BlockSpec((mb, tn), lambda i, j: (i, j)),
+            pl.BlockSpec((mb, tn // block), lambda i, j: (i, j)),
+        ],
+        out_specs=pl.BlockSpec((mb, tn), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
+        interpret=interpret,
+    )(
+        pad2(a2, mp, np_),
+        # pad scales with 1, not 0: pad columns stay finite before the slice
+        pad2(scales.astype(jnp.float32).reshape(m, n // block), mp, np_ // block, 1.0),
+    )
+    return out[:m, :n].reshape(a.shape)
+
+
+def dequant(q, s):
+    """One block's dequantization: integer payload times its scale."""
+    return q.astype(jnp.float32) * s
 
 
 @functools.partial(jax.jit, static_argnames=("block", "bn", "interpret"))
-def dequantize_int8(q, scales, *, block: int = BLOCK, bn: int = 8192, interpret: bool = False):
-    n = q.shape[0]
-    bn = min(bn, n)
-    kernel = functools.partial(_dequant_kernel, block=block)
-    return pl.pallas_call(
-        kernel,
-        grid=(n // bn,),
-        in_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn // block,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
-        interpret=interpret,
-    )(q, scales)
+def dequantize_int8(q, scales, *, block: int = BLOCK, bn: int = LANES * BLOCK,
+                    interpret: bool = False):
+    """(q int8 (..., N), scales fp32 (..., N/block)) -> fp32 (..., N)."""
+    return scaled_map(dequant, q, scales, jnp.float32, block=block, bn=bn,
+                      interpret=interpret)

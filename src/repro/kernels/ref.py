@@ -253,7 +253,8 @@ def selective_scan_step(
 def fedavg_reduce(updates: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
     """(C, N) x (C,) -> (N,): sum_c w_c * u_c / sum_c w_c, fp32 accumulate."""
     wf = weights.astype(jnp.float32)
-    acc = jnp.einsum("c,cn->n", wf, updates.astype(jnp.float32))
+    acc = jnp.einsum("c,cn->n", wf, updates.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
     return (acc / safe_weight_sum(wf)).astype(updates.dtype)
 
 
@@ -288,17 +289,19 @@ def topk_scatter_reduce(
 # int8 block quantization (update compression codec)
 # --------------------------------------------------------------------------
 def quantize_int8(x: jnp.ndarray, block: int = 256) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """x: (N,) fp -> (values int8 (N,), scales fp32 (N/block,)). N % block == 0."""
-    xf = x.astype(jnp.float32).reshape(-1, block)
-    scale = jnp.max(jnp.abs(xf), axis=1) / 127.0
+    """x: (..., N) fp -> (values int8 (..., N), scales fp32 (..., N/block)).
+    N % block == 0."""
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, block)
+    scale = jnp.max(jnp.abs(xf), axis=-1) / 127.0
     scale = jnp.where(scale == 0, 1.0, scale)
-    q = jnp.clip(jnp.round(xf / scale[:, None]), -127, 127).astype(jnp.int8)
-    return q.reshape(-1), scale
+    q = jnp.clip(jnp.round(xf / scale[..., None]), -127, 127).astype(jnp.int8)
+    return q.reshape(x.shape), scale
 
 
 def dequantize_int8(q: jnp.ndarray, scale: jnp.ndarray, block: int = 256) -> jnp.ndarray:
-    qf = q.reshape(-1, block).astype(jnp.float32)
-    return (qf * scale[:, None]).reshape(-1)
+    """(q int8 (..., N), scales (..., N/block)) -> fp32 (..., N)."""
+    qf = q.reshape(*q.shape[:-1], -1, block).astype(jnp.float32)
+    return (qf * scale[..., None]).reshape(q.shape)
 
 
 def collective_pack(x: jnp.ndarray, scales: jnp.ndarray, block: int = 256) -> jnp.ndarray:
@@ -333,5 +336,6 @@ def dequant_reduce(
         scales.astype(jnp.float32)[:, :, None]
     )
     wf = weights.astype(jnp.float32)
-    acc = jnp.einsum("c,cn->n", wf, x.reshape(c, n))
+    acc = jnp.einsum("c,cn->n", wf, x.reshape(c, n),
+                     precision=jax.lax.Precision.HIGHEST)
     return acc / safe_weight_sum(wf)

@@ -6,16 +6,19 @@ and val (C, k) fp32 magnitudes, plus the (C,) aggregation weights.  The
 densify baseline scatters every client into a dense (C, N) fp32 matrix and
 then runs the weighted reduce over it — O(C·N) time AND memory, defeating
 the whole point of shipping k << N entries.  This kernel never builds that
-matrix: grid = (C,), the (N,) fp32 output accumulator stays resident in
-VMEM across all C grid steps (same out-block index every step), and each
-step scatters one client's k weighted values into it:
+matrix.  The payload is flattened to C·k (index, weighted value) entries,
+``w_c * val[c, j]`` formed outside the kernel; grid = (entry chunks,), each
+step streams one chunk of indices and values into SMEM, and the (N,) fp32
+accumulator — laid out as (N/1024, 8, 128) tiles — stays resident in VMEM
+across all grid steps (same out-block index every step).  For each entry
+the kernel loads the one (8, 128) tile that holds it, adds the value at
+its (sublane, lane) through an iota mask, and stores the tile back:
 
-    out[idx[c, j]] += w_c * val[c, j]        for j < k
+    out[idx[c, j]] += w_c * val[c, j]        for every c, j
 
 HBM traffic is the C·k·8-byte payload plus one (N,) result write — the
-wire itself is the roofline.  The inner scatter is a fori_loop of k
-single-element read-modify-writes against VMEM; that serializes k
-lane-granular ops per client, which is the price of arbitrary indices on a
+wire itself is the roofline.  The entry loop serializes one tile
+read-modify-write per entry, which is the price of arbitrary indices on a
 vector unit, but VMEM latency is ~2 orders below HBM and k << N, so the
 loop stays far under the dense path's C·N·4-byte HBM cost.
 
@@ -28,13 +31,14 @@ Contract (mirrors ``ref.topk_scatter_reduce``):
   payload) are DROPPED, identically on kernel and oracle: both sanitize
   before scattering, so neither raw-VMEM writes (here) nor numpy-style
   negative wrapping (XLA scatter) can leak into the aggregate;
-- N needs no alignment: the output is lane-padded internally and the pad
-  is sliced off (in-range indices never touch the pad).
+- N needs no alignment: the accumulator is padded to whole (8, 128)
+  tiles and the pad is sliced off (in-range indices never touch the pad).
 
 Fallback: the (N,) accumulator must fit in VMEM, so ``ops`` dispatches to
 the XLA scatter-add oracle above ``MAX_N_PARAMS`` (derived from this
-file's declared ``VMEM_BUDGET_ELEMS``) — still O(C·k), just not fused.  The only remaining densify path is ``TopKCodec.decode_batch``,
-which exists for callers that *want* the dense per-client matrix.
+file's declared ``VMEM_BUDGET_ELEMS``) — still O(C·k), just not fused.
+The only remaining densify path is ``TopKCodec.decode_batch``, which
+exists for callers that *want* the dense per-client matrix.
 """
 from __future__ import annotations
 
@@ -43,6 +47,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.utils.pytree import safe_weight_sum
 
@@ -53,34 +58,33 @@ from repro.utils.pytree import safe_weight_sum
 # the ~16 MB/core VMEM.
 VMEM_BUDGET_ELEMS = 3 * (1 << 20)
 
-# Worst-case runtime dims the budget is audited against (and that the
-# dispatch gate below enforces for n_params).
-K_MAX = 1 << 15       # TopK payload width (k = frac * N; 0.01 * 3M < 32768)
-C_MAX = 1 << 12       # cohort size of the (1, C) weight row
-# Largest dense accumulator the budget admits beside the payload blocks,
-# with 2x headroom on the payload: the ops dispatch falls back to the XLA
-# scatter-add oracle above this.
-MAX_N_PARAMS = (VMEM_BUDGET_ELEMS - 8 * K_MAX - 2 * C_MAX) // 128 * 128
-VMEM_ELEMS = MAX_N_PARAMS  # back-compat alias for older callers
+# Payload entries per grid step, streamed into SMEM (double-buffered
+# int32 indices + fp32 values: 16 KB of SMEM).
+CHUNK = 1024
+TILE = 8 * 128  # accumulator elements per (8, 128) tile
+# Largest dense accumulator the budget admits beside the entry chunks: the
+# ops dispatch falls back to the XLA scatter-add oracle above this.
+MAX_N_PARAMS = (VMEM_BUDGET_ELEMS - 4 * CHUNK) // TILE * TILE
 
-VMEM_ASSUMES = {"n_params": MAX_N_PARAMS, "k": K_MAX, "c": C_MAX}
+VMEM_ASSUMES = {"n_tiles": MAX_N_PARAMS // TILE}
 
 
-def _scatter_reduce_kernel(idx_ref, val_ref, w_ref, o_ref, *, k: int):
-    c = pl.program_id(0)
-
-    @pl.when(c == 0)
+def _scatter_reduce_kernel(idx_ref, val_ref, o_ref):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    w = w_ref[0, c]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
 
-    def body(j, carry):
-        i = idx_ref[0, j]
-        o_ref[pl.ds(i, 1)] = o_ref[pl.ds(i, 1)] + (w * val_ref[0, j]).reshape(1)
+    def body(e, carry):
+        i = idx_ref[e]
+        t = i // TILE
+        hit = (sub == (i // 128) % 8) & (lane == i % 128)
+        o_ref[t] = o_ref[t] + jnp.where(hit, val_ref[e], 0.0)
         return carry
 
-    jax.lax.fori_loop(0, k, body, 0)
+    jax.lax.fori_loop(0, CHUNK, body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("n_params", "interpret"))
@@ -97,24 +101,24 @@ def topk_scatter_reduce(idx, val, weights, n_params: int, *, interpret: bool = F
     # outside the accumulator by a corrupt payload
     idx = idx.astype(jnp.int32)
     valid = (idx >= 0) & (idx < n_params)
-    idx = jnp.where(valid, idx, 0)
-    val = jnp.where(valid, val.astype(jnp.float32), 0.0)
-
-    pad = (-n_params) % 128  # lane-aligned accumulator; idx < N stays clear
-    np_ = n_params + pad
     wf = weights.astype(jnp.float32)
-    wn = (wf / safe_weight_sum(wf)).reshape(1, c)
+    wn = wf / safe_weight_sum(wf)
+    contrib = jnp.where(valid, val.astype(jnp.float32), 0.0) * wn[:, None]
+    # pad entries (index 0, value 0) fill the last chunk
+    pad = (-(c * k)) % CHUNK
+    idx = jnp.pad(jnp.where(valid, idx, 0).reshape(-1), (0, pad))
+    contrib = jnp.pad(contrib.reshape(-1), (0, pad))
 
+    n_tiles = -(-n_params // TILE)
     out = pl.pallas_call(
-        functools.partial(_scatter_reduce_kernel, k=k),
-        grid=(c,),
+        _scatter_reduce_kernel,
+        grid=((c * k + pad) // CHUNK,),
         in_specs=[
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, c), lambda i: (0, 0)),
+            pl.BlockSpec((CHUNK,), lambda e: (e,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((CHUNK,), lambda e: (e,), memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((np_,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((np_,), jnp.float32),
+        out_specs=pl.BlockSpec((n_tiles, 8, 128), lambda e: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, 8, 128), jnp.float32),
         interpret=interpret,
-    )(idx, val, wn)
-    return out[:n_params] if pad else out
+    )(idx, contrib)
+    return out.reshape(-1)[:n_params]

@@ -12,10 +12,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.models.sharding import shard_map_compat  # noqa: F401  (re-export:
-# launch-side drivers build their shard_maps through the same ONE version
-# shim core.rounds uses)
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)

@@ -25,6 +25,7 @@ from repro.core.cost_model import AWS_DEVICE_FARM, PROFILES, CostModel
 from repro.data.loader import lm_round_batch
 from repro.models import build_model
 from repro.optim import sgd
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.logging import MetricsLogger
 from repro.utils.pytree import tree_bytes, tree_size
 
@@ -51,6 +52,7 @@ def main() -> None:
                          "(Server.run_scanned) instead of the per-round loop")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

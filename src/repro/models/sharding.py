@@ -22,23 +22,18 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, axis_names):
-    """shard_map across jax versions — the ONE shim (used by core.rounds'
-    mesh path, launch-side mesh drivers, and the sharded-client-state tests;
-    it used to live inline in core/rounds.py, where every new mesh caller
-    re-derived it).  Manual over ``axis_names`` (the client axes), automatic
-    over every other mesh axis (the model axes) — the top-level API when
-    present, else the jax.experimental fallback, whose ``auto=`` set
-    expresses the same manual/auto split."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=axis_names, check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as sm
-
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False, auto=auto)
+    """``jax.shard_map``, manual over ``axis_names`` (the client axes) and
+    automatic over every other mesh axis (the model axes) — the ONE place
+    the round engine's mesh path, launch-side mesh drivers and the
+    sharded-client-state tests build their shard_maps.  A model axis of
+    size 1 splits nothing and is made manual too: a Pallas kernel (a codec
+    or collective kernel in the region) cannot be partitioned
+    automatically, so it may only run where no axis is automatic."""
+    manual = set(axis_names) | {a for a, n in mesh.shape.items() if n == 1}
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        axis_names=manual, check_vma=False,
+    )
 
 
 @dataclass(frozen=True)

@@ -28,7 +28,7 @@ from repro.core import (
 from repro.core.compression import fp32_collective_bytes
 from repro.core.cost_model import CostModel, DeviceProfile
 from repro.kernels import ops, ref
-from repro.launch.mesh import collective_tiers
+from repro.launch.mesh import collective_tiers, make_local_mesh
 from repro.models import build_model
 from repro.models.sharding import (
     ShardRules, client_state_shardings, shard_client_state,
@@ -114,7 +114,7 @@ def _setup(seed=0):
 def _client_mesh():
     if len(jax.devices()) < 4:
         pytest.skip("needs >=4 host devices (see conftest.py)")
-    return jax.make_mesh((2, 2), ("pod", "data")), ("pod", "data")
+    return make_local_mesh(pod=2, data=2), ("pod", "data")
 
 
 def _mesh_run(m, params, train, eval_batch, spec, mesh, axes, rounds=12,
@@ -238,7 +238,7 @@ def test_collective_validation_errors():
 def _fsdp_mesh_rules():
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 host devices (see conftest.py)")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_local_mesh(data=4, model=2)
     rules = ShardRules(mode="fsdp", axis_sizes=(("data", 4), ("model", 2)))
     return mesh, rules
 
@@ -358,7 +358,7 @@ def test_round_comm_bytes_mesh_vs_vmap_regression():
 def test_collective_tiers_from_mesh():
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 host devices")
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_local_mesh(pod=2, data=2, model=2)
     assert collective_tiers(mesh, ("pod", "data")) == (("pod", 2), ("data", 2))
     with pytest.raises(ValueError, match="not on mesh"):
         collective_tiers(mesh, ("rack",))

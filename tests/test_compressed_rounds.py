@@ -22,6 +22,7 @@ from repro.core import (
     FedAvg, Int8Codec, NullCodec, RoundSpec, TopKCodec, make_round_step,
 )
 from repro.models import build_model
+from repro.launch.mesh import make_local_mesh
 from repro.optim import sgd
 from repro.utils.pytree import tree_size
 
@@ -54,7 +55,7 @@ def _client_mesh():
     """A 2x2 ("pod", "data") mesh: 4 clients, hierarchical cross-pod psum."""
     if len(jax.devices()) < 4:
         pytest.skip("needs >=4 host devices (see conftest.py)")
-    return jax.make_mesh((2, 2), ("pod", "data")), ("pod", "data")
+    return make_local_mesh(pod=2, data=2), ("pod", "data")
 
 
 def _run(m, params, train, eval_batch, codec, mode="parallel", mesh=None,

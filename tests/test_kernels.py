@@ -315,6 +315,58 @@ def test_quantize_roundtrip_matches_oracle():
     np.testing.assert_allclose(np.asarray(xd), np.asarray(ref.dequantize_int8(qr, sr)), rtol=1e-6)
 
 
+# ---------------- int8 tail tiles ----------------
+# Shapes past one 32768-wide column tile (a column tail), and 40 rows (a row
+# tail past the 32-row tile): the last, partial tiles must be processed,
+# not dropped (a dropped tail leaves NaN scales and garbage payloads).
+TAIL_SHAPES = [(41984,), (3, 41984), (40, 33024)]
+
+
+@pytest.mark.parametrize("shape", TAIL_SHAPES)
+def test_quantize_tail_tiles_match_oracle(shape):
+    x = _randn(shape)
+    q, s = quantize_int8(x, interpret=True)
+    qr, sr = ref.quantize_int8(x)
+    assert q.shape == qr.shape and s.shape == sr.shape
+    assert bool(jnp.all(q == qr))
+    np.testing.assert_allclose(np.asarray(s), np.asarray(sr), rtol=1e-6)
+
+
+@pytest.mark.parametrize("shape", TAIL_SHAPES)
+def test_dequantize_tail_tiles_match_oracle(shape):
+    q, s = ref.quantize_int8(_randn(shape))
+    out = dequantize_int8(q, s, interpret=True)
+    assert out.shape == shape
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(ref.dequantize_int8(q, s)))
+
+
+@pytest.mark.parametrize("n", [41984, 9216 + 2 * 32768])
+def test_collective_pack_unpack_tail_tiles_match_oracle(n):
+    from repro.kernels.collective_quant import collective_pack, collective_unpack
+
+    x = _randn((n,))
+    s = jnp.max(jnp.abs(_randn((n,))).reshape(-1, 256), axis=1) / 127.0
+    q = collective_pack(x, s, interpret=True)
+    np.testing.assert_array_equal(np.asarray(q),
+                                  np.asarray(ref.collective_pack(x, s)))
+    np.testing.assert_array_equal(
+        np.asarray(collective_unpack(q, s, interpret=True)),
+        np.asarray(ref.collective_unpack(q, s)),
+    )
+
+
+@pytest.mark.parametrize("c,n", [(3, 41984), (40, 33024)])
+def test_dequant_reduce_tail_tiles_match_oracle(c, n):
+    q, s = ref.quantize_int8(_randn((c, n)))
+    w = jnp.asarray(RNG.random(c) + 0.1, jnp.float32)
+    out = dequant_reduce(q, s, w, interpret=True)
+    assert out.shape == (n,)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref.dequant_reduce(q, s, w)),
+                               atol=2e-5, rtol=2e-5)
+
+
 @settings(max_examples=20, deadline=None)
 @given(seed=st.integers(0, 1000), scale=st.floats(1e-3, 1e3))
 def test_quantize_error_bound(seed, scale):

@@ -24,6 +24,7 @@ from repro.core import (
 )
 from repro.core.cost_model import CostModel
 from repro.core.server import make_cost_model_for
+from repro.launch.mesh import make_local_mesh
 from repro.data.federated import ClientDataset
 from repro.models import build_model
 from repro.optim import sgd
@@ -271,7 +272,7 @@ def test_mixed_mesh_path_rejected_at_build_time():
     if len(jax.devices()) < 4:
         pytest.skip("needs >=4 host devices (see conftest.py)")
     m, params, _ = _setup()
-    mesh = jax.make_mesh((2, 2), ("pod", "data"))
+    mesh = make_local_mesh(pod=2, data=2)
     spec = RoundSpec(max_steps=STEPS, execution_mode="parallel",
                      codec=_fleet_codec())
     with pytest.raises(NotImplementedError, match="MixedCodec"):

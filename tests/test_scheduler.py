@@ -25,6 +25,7 @@ from repro.core.scheduler import Arrival
 from repro.core.server import make_cost_model_for
 from repro.data.federated import dirichlet_partition
 from repro.data.synthetic import make_features
+from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.optim import sgd
 from repro.utils.pytree import tree_size
@@ -240,7 +241,7 @@ def test_all_ones_mask_is_bitwise_identity(mode, codec_name):
 def test_all_ones_mask_is_bitwise_identity_mesh():
     if len(jax.devices()) < 4:
         pytest.skip("needs >=4 host devices (see conftest.py)")
-    mesh, axes = jax.make_mesh((2, 2), ("pod", "data")), ("pod", "data")
+    mesh, axes = make_local_mesh(pod=2, data=2), ("pod", "data")
     codec = Int8Codec()
     m, params, batch, w, bud = _round_fixture()
     strat = FedAvg()
