@@ -29,7 +29,9 @@ One chip runs these phases:
 ``--chips 4`` runs only the mesh path and what it is compared with:
 ``resnet18-cifar10`` with one client per chip on ``make_local_mesh(data=4)``
 under the fp32 and the int8 collective, against the one-device parallel
-round of the same clients.
+round of the same clients: fp32 params within ``MESH_TOL`` of it per leaf
+(and, as a control, a round with one client dropped outside it), the int8
+eval loss within 5% of it.
 """
 from __future__ import annotations
 
@@ -58,11 +60,16 @@ TOL = {"elementwise": (1e-6, 0.0), "reduce": (1e-5, 1e-6)}
 # most 1/(127 * 8) ~ 1e-3 of the largest update; allow that, per leaf.
 ROUND_TOL = 1e-3
 
-# The fp32 mesh round against the one-device round: the same sums in
-# another order, and per-chip against vmapped convolutions.  Their losses
-# agree to this relative tolerance; parameters whose true update is near
-# zero differ by more than a fixed share of their own update.
-MESH_RTOL = 1e-4
+# The fp32 mesh round against the one-device round, after every round: per
+# leaf, max|mesh - one| / max|one|.  Each chip trains its client unbatched,
+# the one device trains the clients vmapped, and the two round differently
+# even at HIGHEST precision; a ReLU that flips then moves a GroupNorm bias,
+# whose values are no bigger than its updates, by about a percent.  On a
+# TPU v5e the clients trained unbatched on one device came within 1.2e-2
+# of the vmapped round over 3 rounds, the vmapped round at default
+# precision (bf16 passes) 1.1e-1 to 1.4e-1 away, and a round with one of
+# four clients dropped 0.45 to 0.58 away.
+MESH_TOL = 3e-2
 
 CLIENTS, BATCH, STEPS, ROUNDS = 8, 32, 2, 3
 RESNET_PARAMS = 11_173_962
@@ -351,12 +358,28 @@ def allreduce_types(hlo_text: str) -> dict[str, int]:
     return out
 
 
+def leaf_gap(a, b) -> tuple[float, str]:
+    """The worst leaf of max|a - b| / max|b|, and its path."""
+    import jax
+    import numpy as np
+
+    worst, where = 0.0, ""
+    for (path, x), y in zip(jax.tree_util.tree_flatten_with_path(a)[0],
+                            jax.tree.leaves(b)):
+        x, y = np.asarray(x, np.float64), np.asarray(y, np.float64)
+        gap = float(np.max(np.abs(x - y))) / max(float(np.max(np.abs(y))), 1e-30)
+        if gap >= worst:
+            worst, where = gap, jax.tree_util.keystr(path)
+    return worst, where
+
+
 def mesh_phase(model, params, seed: int) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.core import FedAvg, RoundSpec, init_collective_residual, make_round_step
+    from repro.core.rounds import make_client_update
     from repro.launch.mesh import make_local_mesh
     from repro.optim import sgd
 
@@ -370,21 +393,43 @@ def mesh_phase(model, params, seed: int) -> None:
     spec = RoundSpec(max_steps=STEPS, execution_mode="parallel")
     eval_batch = jax.tree.map(lambda a: a[0, 0], batches)
 
-    def run(step, cstate):
-        """3 rounds -> (client losses, final eval loss)."""
+    def run(step, cstate, weights):
+        """ROUNDS rounds -> (client losses, final eval loss, params after
+        each round on the host)."""
         p, state = params, strat.init_state(params)
-        losses = []
+        losses, ps = [], []
         for rnd in range(ROUNDS):
-            p, state, cstate, met = step(p, state, cstate, batches, w, bud, rnd)
+            p, state, cstate, met = step(p, state, cstate, batches, weights, bud, rnd)
             losses.append(float(met["client_loss_mean"]))
-        return losses, float(model.loss_fn(p, eval_batch)[0])
+            ps.append(jax.device_get(p))
+        return losses, float(model.loss_fn(p, eval_batch)[0]), ps
+
+    def gaps(ps):
+        """The worst leaf gap to the one-device run over all rounds."""
+        return max(leaf_gap(p, q) for p, q in zip(ps, p_one))
+
+    def unbatched():
+        """The round's clients trained one by one on one device, without
+        vmap, as each chip trains its client, and their deltas averaged ->
+        params after each round on the host."""
+        update = jax.jit(make_client_update(model.loss_fn, sgd(RESNET_LR), spec))
+        p, state, ps = params, strat.init_state(params), []
+        for rnd in range(ROUNDS):
+            news = [update(p, jax.tree.map(lambda x: x[i], batches), bud[i])[0]
+                    for i in range(n_chips)]
+            avg = jax.tree.map(lambda g, *ns: g + sum(n - g for n in ns) / n_chips,
+                               p, *news)
+            p, state = strat.server_update(avg, p, state, rnd)
+            ps.append(jax.device_get(p))
+        return ps
 
     t0 = time.perf_counter()
     one = jax.jit(make_round_step(model.loss_fn, sgd(RESNET_LR), strat, spec))
-    l_one, e_one = run(one, ())
+    l_one, e_one, p_one = run(one, (), w)
     print(f"[mesh/one-device] losses {l_one}, eval loss {e_one:.6f}; "
           f"{time.perf_counter() - t0:.1f} s", flush=True)
     check(all(np.isfinite(l_one)), f"one-device losses {l_one}")
+    p_unbatched = unbatched()
 
     for collective in ("fp32", "int8"):
         t0 = time.perf_counter()
@@ -398,16 +443,30 @@ def mesh_phase(model, params, seed: int) -> None:
         text = step.lower(*args).compile().as_text()
         types = allreduce_types(text)
         counts = kernel_counts(text)
-        losses, ev = run(step, cstate)
+        losses, ev, ps = run(step, cstate, w)
+        gap, leaf = gaps(ps)
         print(f"[mesh/{collective}] elements all-reduced by type {types}; "
               f"tpu_custom_call by kernel {counts}; losses {losses}, eval loss "
-              f"{ev:.6f}; {time.perf_counter() - t0:.1f} s", flush=True)
+              f"{ev:.6f}; params vs one-device: worst leaf max|diff| / max|param| "
+              f"= {gap:.3e} at {leaf}; {time.perf_counter() - t0:.1f} s", flush=True)
         check(all(np.isfinite(losses)), f"{collective}: losses {losses}")
         if collective == "fp32":
-            # the same sums in another order: every round's client loss and
-            # the final eval loss agree with the one-device run to MESH_RTOL
-            np.testing.assert_allclose(losses + [ev], l_one + [e_one],
-                                       rtol=MESH_RTOL, err_msg="fp32 mesh")
+            # where the gap comes from: the same clients trained unbatched
+            # on one device (printed, not checked)
+            gap_u, leaf_u = max(leaf_gap(p, q) for p, q in zip(ps, p_unbatched))
+            gap_uo, leaf_uo = gaps(p_unbatched)
+            print(f"[mesh/fp32] unbatched one-device clients: vs mesh {gap_u:.3e} "
+                  f"at {leaf_u}; vs the vmapped one-device round {gap_uo:.3e} "
+                  f"at {leaf_uo}", flush=True)
+            check(gap <= MESH_TOL, f"fp32: params differ from one device "
+                  f"({gap:.3e} > {MESH_TOL:g} at {leaf})")
+            # control: the same program with one client's weight zeroed
+            # must fail the comparison, or the comparison proves nothing
+            _, _, ps_drop = run(step, cstate, w.at[-1].set(0.0))
+            gap_drop, leaf_drop = gaps(ps_drop)
+            print(f"[mesh/fp32] control, one client dropped: worst leaf "
+                  f"{gap_drop:.3e} at {leaf_drop}", flush=True)
+            check(gap_drop > MESH_TOL, "fp32: the comparison misses a dropped client")
         else:
             check(counts.get("collective_pack", 0) > 0
                   and counts.get("collective_unpack", 0) > 0,

@@ -626,12 +626,14 @@ class NullCodec(UpdateCodec):
         """Leafwise fp32 weighted mean — the fp32 wire loses nothing, so the
         uncompressed path never flattens the model into one (C, N) matrix
         (same reasoning as the identity ``transmit_tree``).  Each leaf's
-        (C, leaf size) delta block goes through the fedavg reduce kernel."""
+        (C, leaf size) client params go through the fedavg reduce kernel,
+        centered on the global leaf: the mean delta comes out in fp32 and
+        no (C, leaf size) delta matrix is written."""
 
         def leaf_mean(xs, g):
             gf = g.astype(jnp.float32)
-            delta = xs.astype(jnp.float32).reshape(xs.shape[0], -1) - gf.reshape(-1)
-            mean = ops.fedavg_reduce(delta, weights)
+            mean = ops.fedavg_reduce(xs.reshape(xs.shape[0], -1), weights,
+                                     gf.reshape(-1))
             return (gf + mean.reshape(g.shape)).astype(g.dtype)
 
         # state passes through unchanged (() flat; a tuple of ()s segmented)

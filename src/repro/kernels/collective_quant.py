@@ -20,7 +20,7 @@ and the int32 sum cannot overflow below a 2**31/127 ~= 16.9M-device fan-in.
 
 Both are ``quantize.scaled_map`` kernels: one HBM pass over the lane-dense
 (N,) operand and its (N/block,) scales, with the same tiling, ceil-division
-grid and pad as the uplink quantizer (``quantize.py``).
+grid as the uplink quantizer (``quantize.py``).
 """
 from __future__ import annotations
 

@@ -25,7 +25,7 @@ from jax.experimental import pallas as pl
 
 from repro.utils.pytree import safe_weight_sum
 
-from .quantize import BLOCK, LANES, block_scale, pad2, tiling
+from .quantize import BLOCK, LANES, block_scale, tiling
 
 # Static VMEM ceiling audited by fedlint (pallas-vmem-budget), in
 # fp32-equivalent elements (the int8 tile is costed at fp32 — the kernel
@@ -35,17 +35,26 @@ VMEM_BUDGET_ELEMS = 3 * (1 << 20)
 VMEM_ASSUMES = {"mb": 32, "tn": 128 * 256, "block": 256}
 
 
-def _dequant_reduce_kernel(w_ref, q_ref, s_ref, o_ref, *, block: int):
+def _dequant_reduce_kernel(w_ref, q_ref, s_ref, o_ref, *, block: int, c: int):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
     w = w_ref[...]                                      # (cb, 1) weights
     s = s_ref[...]                                      # (cb, tn/block)
+    valid = None
+    if c % w.shape[0]:
+        # the last client tile is partial: its rows past C are unspecified
+        # (they may hold NaN), so they are zeroed, not just weighted by 0
+        row = pl.program_id(1) * w.shape[0] + jax.lax.broadcasted_iota(
+            jnp.int32, w.shape, 0)
+        valid = row < c
     for g in range(q_ref.shape[1] // block):
         cols = slice(g * block, (g + 1) * block)
-        x = q_ref[:, cols].astype(jnp.float32) * block_scale(s, g)
-        o_ref[:, cols] += jnp.sum(w * x, axis=0, keepdims=True)
+        x = w * (q_ref[:, cols].astype(jnp.float32) * block_scale(s, g))
+        if valid is not None:
+            x = jnp.where(valid, x, 0.0)
+        o_ref[:, cols] += jnp.sum(x, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "bn", "interpret"))
@@ -55,31 +64,28 @@ def dequant_reduce(
 ):
     """(C,N) int8 x (C,N/block) fp32 x (C,) -> (N,) fp32 weighted mean.
 
-    N % block == 0 (the encoder pads).  Clients and columns are padded up
-    to whole tiles with zero payload and zero weight (no contribution);
-    the pad is sliced off the result.  Weights are auto-normalized.
+    N % block == 0 (the encoder pads).  The grid is a ceiling division:
+    the last column tile is partial (reads padded, writes masked), and the
+    rows of a partial last client tile are masked out of the sum.  Weights
+    are auto-normalized.
     """
     c, n = q.shape
     assert n % block == 0, f"N={n} not a multiple of block={block}"
     assert scales.shape == (c, n // block), scales.shape
-    mb, mp, tn, np_ = tiling(c, n, bn, block)
+    mb, tn, (row_tiles, col_tiles) = tiling(c, n, bn, block)
     wf = weights.astype(jnp.float32)
     wn = (wf / safe_weight_sum(wf)).reshape(c, 1)
 
     out = pl.pallas_call(
-        functools.partial(_dequant_reduce_kernel, block=block),
-        grid=(np_ // tn, mp // mb),
+        functools.partial(_dequant_reduce_kernel, block=block, c=c),
+        grid=(col_tiles, row_tiles),
         in_specs=[
             pl.BlockSpec((mb, 1), lambda i, j: (j, 0)),
             pl.BlockSpec((mb, tn), lambda i, j: (j, i)),
             pl.BlockSpec((mb, tn // block), lambda i, j: (j, i)),
         ],
         out_specs=pl.BlockSpec((1, tn), lambda i, j: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, np_), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
-    )(
-        pad2(wn, mp, 1),
-        pad2(q, mp, np_),
-        pad2(scales.astype(jnp.float32), mp, np_ // block),
-    )
-    return out[0, :n]
+    )(wn, q, scales.astype(jnp.float32))
+    return out[0]

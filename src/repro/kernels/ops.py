@@ -97,17 +97,20 @@ def _denormalize(out, weights):
     return out * safe_weight_sum(weights.astype(jnp.float32)).astype(out.dtype)
 
 
-def fedavg_reduce(updates, weights, *, interpret=False, normalize=True):
+def fedavg_reduce(updates, weights, center=None, *, interpret=False,
+                  normalize=True):
+    """(C, N) x (C,) -> (N,) weighted mean; with a ``center`` (N,), the fp32
+    mean of ``updates - center``, without writing the difference."""
     if _use_pallas() or interpret:
         from .fedavg_reduce import fedavg_reduce as fr
 
-        # the kernel pads N up to a lane-aligned tile itself: no shape gate
+        # the kernel covers a tail tile itself: no shape gate
         out = fr(
-            updates, weights,
+            updates, weights, center,
             interpret=interpret or jax.default_backend() != "tpu",
         )
     else:
-        out = ref.fedavg_reduce(updates, weights)
+        out = ref.fedavg_reduce(updates, weights, center)
     return out if normalize else _denormalize(out, weights)
 
 

@@ -8,9 +8,12 @@ tiles).  A column tile holds 128 scale blocks, so the scales of a tile are
 one lane-dense (rows, 128) tile; the kernel walks the tile's blocks with
 static 256-lane slices, and puts each block's scale in its lane through an
 iota mask.  An N that is no wider than one column tile is one full-width
-tile.  Rows and columns are padded up to whole tiles (ceil-division grid),
-so a tail that is not a tile multiple is processed too, and the pad is
-sliced off.  Dequantize reverses it.
+tile.  The grid is a ceiling division: Pallas pads the reads of the last,
+partial tile and masks its writes, so a tail that is not a tile multiple
+is processed in place, with no padded copy of the operand.  Rows are
+independent and a 256-block lies wholly inside or wholly outside the
+operand (tiles are whole blocks), so the padded lanes never reach a valid
+output.  Dequantize reverses it.
 
 The scaled elementwise kernels of ``collective_quant.py`` and the fused
 reduce of ``dequant_reduce.py`` share this tiling (``tiling``) and the
@@ -37,20 +40,14 @@ VMEM_BUDGET_ELEMS = 9 * (1 << 19)
 VMEM_ASSUMES = {"mb": ROW_TILE, "tn": LANES * BLOCK, "block": BLOCK}
 
 
-def tiling(m: int, n: int, bn: int, block: int) -> tuple[int, int, int, int]:
-    """(rows per tile, padded M, columns per tile, padded N) for an (M, N)
-    operand with ``block``-wide scale blocks; ``bn`` asks for a column tile
-    width, rounded to whole scale tiles of LANES blocks."""
+def tiling(m: int, n: int, bn: int, block: int) -> tuple[int, int, tuple[int, int]]:
+    """(rows per tile, columns per tile, (row tiles, column tiles)) for an
+    (M, N) operand with ``block``-wide scale blocks; ``bn`` asks for a
+    column tile width, rounded to whole scale tiles of LANES blocks."""
     mb = m if m <= ROW_TILE else ROW_TILE
     unit = LANES * block
     tn = n if n <= unit else max(unit, bn // unit * unit)
-    return mb, -(-m // mb) * mb, tn, -(-n // tn) * tn
-
-
-def pad2(a, rows: int, cols: int, value=0):
-    """Pad a 2-D array up to (rows, cols) with ``value``."""
-    pr, pc = rows - a.shape[0], cols - a.shape[1]
-    return jnp.pad(a, ((0, pr), (0, pc)), constant_values=value) if pr or pc else a
+    return mb, tn, (pl.cdiv(m, mb), pl.cdiv(n, tn))
 
 
 def block_scale(s, g: int):
@@ -87,26 +84,23 @@ def quantize_int8(x, *, block: int = BLOCK, bn: int = LANES * BLOCK,
     N % block == 0."""
     x2 = _as_rows(x, block)
     m, n = x2.shape
-    mb, mp, tn, np_ = tiling(m, n, bn, block)
+    mb, tn, grid = tiling(m, n, bn, block)
     q, s = pl.pallas_call(
         functools.partial(_quant_kernel, block=block),
-        grid=(mp // mb, np_ // tn),
+        grid=grid,
         in_specs=[pl.BlockSpec((mb, tn), lambda i, j: (i, j))],
         out_specs=[
             pl.BlockSpec((mb, tn), lambda i, j: (i, j)),
             pl.BlockSpec((mb, tn // block), lambda i, j: (i, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((mp, np_), jnp.int8),
-            jax.ShapeDtypeStruct((mp, np_ // block), jnp.float32),
+            jax.ShapeDtypeStruct((m, n), jnp.int8),
+            jax.ShapeDtypeStruct((m, n // block), jnp.float32),
         ],
         interpret=interpret,
-    )(pad2(x2, mp, np_))
+    )(x2)
     lead = x.shape[:-1]
-    return (
-        q[:m, :n].reshape(*lead, n),
-        s[:m, : n // block].reshape(*lead, n // block),
-    )
+    return q.reshape(*lead, n), s.reshape(*lead, n // block)
 
 
 def scaled_map(fn, a, scales, out_dtype, *, block: int, bn: int,
@@ -115,7 +109,7 @@ def scaled_map(fn, a, scales, out_dtype, *, block: int, bn: int,
     ``a`` and its (..., N/block) ``scales``, one HBM pass."""
     a2 = _as_rows(a, block)
     m, n = a2.shape
-    mb, mp, tn, np_ = tiling(m, n, bn, block)
+    mb, tn, grid = tiling(m, n, bn, block)
 
     def kernel(a_ref, s_ref, o_ref):
         s = s_ref[...]
@@ -125,20 +119,16 @@ def scaled_map(fn, a, scales, out_dtype, *, block: int, bn: int,
 
     out = pl.pallas_call(
         kernel,
-        grid=(mp // mb, np_ // tn),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((mb, tn), lambda i, j: (i, j)),
             pl.BlockSpec((mb, tn // block), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((mb, tn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         interpret=interpret,
-    )(
-        pad2(a2, mp, np_),
-        # pad scales with 1, not 0: pad columns stay finite before the slice
-        pad2(scales.astype(jnp.float32).reshape(m, n // block), mp, np_ // block, 1.0),
-    )
-    return out[:m, :n].reshape(a.shape)
+    )(a2, scales.astype(jnp.float32).reshape(m, n // block))
+    return out.reshape(a.shape)
 
 
 def dequant(q, s):

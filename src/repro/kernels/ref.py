@@ -250,12 +250,17 @@ def selective_scan_step(
 # --------------------------------------------------------------------------
 # FedAvg weighted aggregation (the server hotspot)
 # --------------------------------------------------------------------------
-def fedavg_reduce(updates: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
-    """(C, N) x (C,) -> (N,): sum_c w_c * u_c / sum_c w_c, fp32 accumulate."""
+def fedavg_reduce(updates: jnp.ndarray, weights: jnp.ndarray,
+                  center: jnp.ndarray | None = None) -> jnp.ndarray:
+    """(C, N) x (C,) -> (N,): sum_c w_c * u_c / sum_c w_c, fp32 accumulate.
+    With a ``center`` (N,), the fp32 mean of ``updates - center``."""
     wf = weights.astype(jnp.float32)
-    acc = jnp.einsum("c,cn->n", wf, updates.astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
-    return (acc / safe_weight_sum(wf)).astype(updates.dtype)
+    u = updates.astype(jnp.float32)
+    if center is not None:
+        u = u - center.astype(jnp.float32)
+    acc = jnp.einsum("c,cn->n", wf, u, precision=jax.lax.Precision.HIGHEST)
+    out_dtype = updates.dtype if center is None else jnp.float32
+    return (acc / safe_weight_sum(wf)).astype(out_dtype)
 
 
 def topk_scatter_reduce(

@@ -12,6 +12,7 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
 imports every test file.
 """
+import importlib.util
 import os
 
 import jax
@@ -33,6 +34,8 @@ TAIL = 3 * 32768 + 9216         # a multiple of 256, not of any tile
 
 @pytest.fixture(scope="module")
 def one_chip():
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("no TPU compiler (libtpu) in this installation")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
 
@@ -44,11 +47,9 @@ def one_chip():
         topo = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
-    except Exception as e:  # no TPU compiler in this installation
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
 def _compile_text(fn, sharding, *shapes):
@@ -63,6 +64,12 @@ CASES = {
         fedavg_reduce.fedavg_reduce, [((C, N), F32), ((C,), F32)]),
     "fedavg_reduce_tail": (
         fedavg_reduce.fedavg_reduce, [((C, TAIL + 100), F32), ((C,), F32)]),
+    # the Null round: client params of one leaf centered on the global leaf
+    "fedavg_reduce_centered_leaf": (
+        fedavg_reduce.fedavg_reduce,
+        [((C, LEAF), F32), ((C,), F32), ((LEAF,), F32)]),
+    "fedavg_reduce_centered_small_leaf": (
+        fedavg_reduce.fedavg_reduce, [((C, 10), F32), ((C,), F32), ((10,), F32)]),
     "quantize_int8": (
         quantize.quantize_int8, [((C, NP), F32)]),
     "quantize_int8_leaf_tail": (

@@ -137,6 +137,22 @@ def test_fedavg_reduce_tail_block(c, n, bn):
     )
 
 
+@pytest.mark.parametrize("c,n,bn", [(4, 5000, 4096), (8, 10, 8192), (3, 8192, 2048)])
+def test_fedavg_reduce_centered_matches_oracle(c, n, bn):
+    """The centered reduce is the fp32 mean of the client deltas, tail
+    tile included (the Null round's leafwise aggregation)."""
+    u = _randn((c, n))
+    g = _randn((n,))
+    w = jnp.asarray(RNG.random(c) + 0.1, jnp.float32)
+    out = fedavg_reduce(u, w, g, interpret=True, bn=bn)
+    exp = ref.fedavg_reduce(u, w, g)
+    assert out.shape == (n,) and out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(exp), np.asarray(ref.fedavg_reduce(u - g, w)), atol=1e-6, rtol=1e-6
+    )
+
+
 @pytest.mark.parametrize("c,n,bn", [(4, 8192, 4096), (3, 5120, 2048), (2, 2048, 2048)])
 def test_dequant_reduce_matches_oracle(c, n, bn):
     """Fused dequantize+weighted-reduce == dequantize rows then fedavg_reduce."""
