@@ -166,9 +166,10 @@ class JaxClient(Client):
         mask = self.trainable_mask
 
         def total_loss(params, batch, global_params):
-            loss, metrics = self.loss_fn(params, batch)
-            if mu > 0:
-                loss = loss + 0.5 * mu * tree_sq_norm(tree_sub(params, global_params))
+            with jax.named_scope("fl.local.loss"):
+                loss, metrics = self.loss_fn(params, batch)
+                if mu > 0:
+                    loss = loss + 0.5 * mu * tree_sq_norm(tree_sub(params, global_params))
             return loss, metrics
 
         @jax.jit
@@ -180,15 +181,17 @@ class JaxClient(Client):
                 (loss, _), grads = jax.value_and_grad(total_loss, has_aux=True)(
                     params, batch, global_params
                 )
-                new_params, new_opt = opt.update(grads, params, opt_state, i)
-                if mask is not None:
-                    new_params = jax.tree.map(
-                        lambda n, o, m: n if m else o, new_params, params, mask
-                    )
-                live = i < budget
-                params = tree_where(live, new_params, params)
-                opt_state = tree_where(live, new_opt, opt_state)
-                return (params, opt_state, i + 1), jnp.where(live, loss, 0.0)
+                with jax.named_scope("fl.local.update"):
+                    new_params, new_opt = opt.update(grads, params, opt_state, i)
+                    if mask is not None:
+                        new_params = jax.tree.map(
+                            lambda n, o, m: n if m else o, new_params, params, mask
+                        )
+                    live = i < budget
+                    params = tree_where(live, new_params, params)
+                    opt_state = tree_where(live, new_opt, opt_state)
+                    loss = jnp.where(live, loss, 0.0)
+                return (params, opt_state, i + 1), loss
 
             (params, _, _), losses = jax.lax.scan(
                 step, (global_params, opt_state, jnp.zeros((), jnp.int32)), batches
@@ -223,8 +226,9 @@ class JaxClient(Client):
         mu = float(cfg.get("mu", 0.0))
         lr = float(cfg.get("lr", 0.0))
 
-        batches = [self.dataset.next_batch(self.batch_size) for _ in range(full_steps)]
-        stacked = {k: np.stack([b[k] for b in batches]) for k in batches[0]}
+        with jax.profiler.TraceAnnotation("fl.fit.batch"):
+            batches = [self.dataset.next_batch(self.batch_size) for _ in range(full_steps)]
+            stacked = {k: np.stack([b[k] for b in batches]) for k in batches[0]}
 
         # lr == 0.0 means the built closure captures self.optimizer, so the
         # optimizer's identity must be part of the key — without it, two
@@ -238,14 +242,16 @@ class JaxClient(Client):
         if cache_key not in _GLOBAL_FIT_CACHE:
             _GLOBAL_FIT_CACHE[cache_key] = self._build_fit(full_steps, mu, lr)
         fit_steps = _GLOBAL_FIT_CACHE[cache_key]
-        params, mean_loss = fit_steps(
-            ins.parameters, stacked, jnp.asarray(budget, jnp.int32)
-        )
+        with jax.profiler.TraceAnnotation("fl.fit.step"):
+            params, mean_loss = fit_steps(
+                ins.parameters, stacked, jnp.asarray(budget, jnp.int32)
+            )
         self._params = params
-        steps_done = min(budget, full_steps)
+        with jax.profiler.TraceAnnotation("fl.fit.sync"):
+            loss = float(mean_loss)
         metrics = {
-            "loss": float(mean_loss),
-            "steps_done": steps_done,
+            "loss": loss,
+            "steps_done": min(budget, full_steps),
             "device_profile": self.device_profile,
         }
 
@@ -269,10 +275,11 @@ class JaxClient(Client):
                 or residual.shape != (n_params,)
             ):
                 residual = jnp.zeros((n_params,), jnp.float32)
-            enc, self._residual = compress_update(
-                codec, params, ins.parameters, residual=residual
-            )
-            wire = compress_to_wire(codec, enc, n_params)
+            with jax.profiler.TraceAnnotation("fl.fit.encode"):
+                enc, self._residual = compress_update(
+                    codec, params, ins.parameters, residual=residual
+                )
+                wire = compress_to_wire(codec, enc, n_params)
             metrics["wire_bytes"] = wire.num_bytes
             return FitRes(
                 parameters=wire, num_examples=len(self.dataset), metrics=metrics,

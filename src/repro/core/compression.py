@@ -469,9 +469,10 @@ class UpdateCodec:
         return jnp.concatenate(parts), tuple(new_state)
 
     def _aggregate_batch_flat(self, deltas, weights, state):
-        eff = deltas + state
-        enc = self.encode_batch(eff)
-        new_state = eff - self.decode_batch(enc)
+        with jax.named_scope("fl.encode"):
+            eff = deltas + state
+            enc = self.encode_batch(eff)
+            new_state = eff - self.decode_batch(enc)
         return self.reduce(enc, weights), new_state
 
     def aggregate_segment_batch(self, deltas, weights, state, seg: Segment):
@@ -802,10 +803,11 @@ class TopKCodec(UpdateCodec):
         payload, and zero the transmitted coordinates out of the error-
         feedback state — TopK transmits exact values, so
         ``eff - decode(enc) == eff`` zeroed at idx; no dense decode."""
-        eff = deltas + state
-        enc = self.encode_batch(eff)
-        rows = jnp.arange(eff.shape[0])[:, None]
-        new_state = eff.at[rows, enc["idx"]].set(0.0)
+        with jax.named_scope("fl.encode"):
+            eff = deltas + state
+            enc = self.encode_batch(eff)
+            rows = jnp.arange(eff.shape[0])[:, None]
+            new_state = eff.at[rows, enc["idx"]].set(0.0)
         return self.reduce(enc, weights), new_state
 
     def transmit_segment(self, vec: jnp.ndarray, state_row, seg: Segment):
@@ -932,18 +934,19 @@ class LoRACodec(UpdateCodec):
         c = deltas.shape[0]
         m, n = seg.matrix_shape
         r = self._eff_rank(seg)
-        eff = deltas.astype(jnp.float32) + state
-        x = eff.reshape(c, m, n)
-        key = self._seg_key(seg)  # one shared basis: clients and server agree
-        a, b = jax.vmap(lambda xi: self._factorize(xi, key))(x)
-        # factor wire round-trip (what the server can actually see)
-        fa = self.factor_codec.decode_batch(
-            self.factor_codec.encode_batch(a.reshape(c, m * r))
-        ).reshape(c, m, r)
-        fb = self.factor_codec.decode_batch(
-            self.factor_codec.encode_batch(b.reshape(c, r * n))
-        ).reshape(c, r, n)
-        dec = jnp.einsum("cmr,crn->cmn", fa, fb)
+        with jax.named_scope("fl.encode"):
+            eff = deltas.astype(jnp.float32) + state
+            x = eff.reshape(c, m, n)
+            key = self._seg_key(seg)  # one shared basis: clients and server agree
+            a, b = jax.vmap(lambda xi: self._factorize(xi, key))(x)
+            # factor wire round-trip (what the server can actually see)
+            fa = self.factor_codec.decode_batch(
+                self.factor_codec.encode_batch(a.reshape(c, m * r))
+            ).reshape(c, m, r)
+            fb = self.factor_codec.decode_batch(
+                self.factor_codec.encode_batch(b.reshape(c, r * n))
+            ).reshape(c, r, n)
+            dec = jnp.einsum("cmr,crn->cmn", fa, fb)
         wf = weights.astype(jnp.float32)
         mean = jnp.einsum("c,cmn->mn", wf, dec) / safe_weight_sum(wf)
         return mean.reshape(-1), eff - dec.reshape(c, -1)

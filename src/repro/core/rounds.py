@@ -195,13 +195,14 @@ def make_client_update(
     """
 
     def total_loss(params, batch, global_params):
-        loss, metrics = loss_fn(params, batch)
-        if spec.prox_mu > 0.0:
-            from repro.utils.pytree import tree_sq_norm, tree_sub
+        with jax.named_scope("fl.local.loss"):
+            loss, metrics = loss_fn(params, batch)
+            if spec.prox_mu > 0.0:
+                from repro.utils.pytree import tree_sq_norm, tree_sub
 
-            loss = loss + 0.5 * spec.prox_mu * tree_sq_norm(
-                tree_sub(params, global_params)
-            )
+                loss = loss + 0.5 * spec.prox_mu * tree_sq_norm(
+                    tree_sub(params, global_params)
+                )
         return loss, metrics
 
     def client_update(global_params, batches, step_budget):
@@ -243,15 +244,17 @@ def make_client_update(
             params, opt_state, i = carry
             batch = xs
             loss, grads = grad_of(params, batch)
-            new_params, new_opt_state = opt.update(grads, params, opt_state, i)
-            if trainable_mask is not None:
-                new_params = jax.tree.map(
-                    lambda n, o, m: n if m else o, new_params, params, trainable_mask
-                )
-            live = i < step_budget
-            params = tree_where(live, new_params, params)
-            opt_state = tree_where(live, new_opt_state, opt_state)
-            loss = jnp.where(live, loss, 0.0)
+            with jax.named_scope("fl.local.update"):
+                new_params, new_opt_state = opt.update(grads, params, opt_state, i)
+                if trainable_mask is not None:
+                    new_params = jax.tree.map(
+                        lambda n, o, m: n if m else o, new_params, params,
+                        trainable_mask,
+                    )
+                live = i < step_budget
+                params = tree_where(live, new_params, params)
+                opt_state = tree_where(live, new_opt_state, opt_state)
+                loss = jnp.where(live, loss, 0.0)
             return (params, opt_state, i + 1), loss
 
         (params, _, _), losses = jax.lax.scan(
@@ -432,7 +435,8 @@ def make_round_step(
                 new_p, global_params,
             )
             state_row = jax.tree.map(lambda x: x[0], codec_state)
-            dec_delta, new_row = codec.transmit_tree(delta, state_row)
+            with jax.named_scope("fl.encode"):
+                dec_delta, new_row = codec.transmit_tree(delta, state_row)
             if mask_c is not None:
                 # participation mask: a dropped client never transmitted —
                 # its residual row carries unchanged across the round, and
@@ -465,10 +469,11 @@ def make_round_step(
                         wx = jax.lax.psum(wx, ax)
                     return wx / wsum
 
-                avg = jax.tree.map(
-                    lambda g, d: (g.astype(jnp.float32) + wmean(d)).astype(g.dtype),
-                    global_params, dec_delta,
-                )
+                with jax.named_scope("fl.reduce"):
+                    avg = jax.tree.map(
+                        lambda g, d: (g.astype(jnp.float32) + wmean(d)).astype(g.dtype),
+                        global_params, dec_delta,
+                    )
                 return avg, loss[None], steps[None], jax.tree.map(
                     lambda x: x[None], new_row
                 )
@@ -497,15 +502,16 @@ def make_round_step(
 
             leaves_d, treedef = jax.tree_util.tree_flatten(dec_delta)
             leaves_r = treedef.flatten_up_to(resid_row)
-            pairs = [leaf_psum(d, r) for d, r in zip(leaves_d, leaves_r)]
-            sums = jax.tree_util.tree_unflatten(treedef, [p[0] for p in pairs])
-            new_resid_row = jax.tree_util.tree_unflatten(
-                treedef, [p[1] for p in pairs]
-            )
-            avg = jax.tree.map(
-                lambda g, s: (g.astype(jnp.float32) + s / wsum).astype(g.dtype),
-                global_params, sums,
-            )
+            with jax.named_scope("fl.reduce"):
+                pairs = [leaf_psum(d, r) for d, r in zip(leaves_d, leaves_r)]
+                sums = jax.tree_util.tree_unflatten(treedef, [p[0] for p in pairs])
+                new_resid_row = jax.tree_util.tree_unflatten(
+                    treedef, [p[1] for p in pairs]
+                )
+                avg = jax.tree.map(
+                    lambda g, s: (g.astype(jnp.float32) + s / wsum).astype(g.dtype),
+                    global_params, sums,
+                )
             return avg, loss[None], steps[None], (
                 jax.tree.map(lambda x: x[None], new_row),
                 jax.tree.map(lambda x: x[None], new_resid_row),
@@ -543,9 +549,10 @@ def make_round_step(
                 out_specs=(param_specs_manual, P(axes), P(axes), state_specs),
                 axis_names=set(axes),
             )(*args)
-            new_global, new_state = strategy.server_update(
-                avg, global_params, server_state, rnd
-            )
+            with jax.named_scope("fl.server_update"):
+                new_global, new_state = strategy.server_update(
+                    avg, global_params, server_state, rnd
+                )
             metrics = {
                 # examples-weighted, like every other execution mode: the
                 # same round must report the same metric everywhere
@@ -598,17 +605,20 @@ def make_round_step(
             w_agg = weights if mask is None else (
                 weights.astype(jnp.float32) * mask.astype(jnp.float32)
             )
-            avg_params, new_client_state = codec.aggregate_updates(
-                new_params, global_params, w_agg, client_state
-            )
+            # the codec's encode side runs under its own fl.encode scope
+            with jax.named_scope("fl.reduce"):
+                avg_params, new_client_state = codec.aggregate_updates(
+                    new_params, global_params, w_agg, client_state
+                )
             if mask is not None:
                 # ...and, having transmitted nothing, keeps its residual row
                 new_client_state = _carry_masked_state(
                     codec, mask, client_state, new_client_state
                 )
-            new_global, new_state = strategy.server_update(
-                avg_params, global_params, server_state, rnd
-            )
+            with jax.named_scope("fl.server_update"):
+                new_global, new_state = strategy.server_update(
+                    avg_params, global_params, server_state, rnd
+                )
             metrics = {
                 # examples-weighted (matches the sequential scan's running
                 # weighted mean): one metric definition across all modes
@@ -648,7 +658,8 @@ def make_round_step(
                 )
                 delta = jax.tree.map(jnp.subtract, new_params, global_params)
                 # codec round-trip: only what survives the wire is accumulated
-                dec_delta, new_row = codec_g.transmit_tree(delta, state_row)
+                with jax.named_scope("fl.encode"):
+                    dec_delta, new_row = codec_g.transmit_tree(delta, state_row)
                 if m is not None:
                     # masked client: zero aggregation weight AND a zeroed
                     # delta (0 * NaN from a diverged dropped client would
@@ -667,11 +678,12 @@ def make_round_step(
                     steps = jnp.where(m > 0, steps, 0)
                 else:
                     loss_for_max = loss
-                scale = (w / wsum).astype(jnp.bfloat16)
-                delta_acc = _pin(jax.tree.map(
-                    lambda acc, d: acc + scale * d.astype(jnp.bfloat16),
-                    delta_acc, dec_delta,
-                ))
+                with jax.named_scope("fl.reduce"):
+                    scale = (w / wsum).astype(jnp.bfloat16)
+                    delta_acc = _pin(jax.tree.map(
+                        lambda acc, d: acc + scale * d.astype(jnp.bfloat16),
+                        delta_acc, dec_delta,
+                    ))
                 carry = (
                     delta_acc,
                     loss_acc + loss * w / wsum,
@@ -724,13 +736,15 @@ def make_round_step(
             loss_max = jnp.where(any_live, loss_max, jnp.nan)
         # the averaged delta goes straight through server_update (FedAvg:
         # identity; FedOpt: server optimizer) — no stacked fp32 detour.
-        avg_params = _pin(jax.tree.map(
-            lambda g, d: (g.astype(jnp.float32) + d.astype(jnp.float32)).astype(g.dtype),
-            global_params, delta,
-        ))
-        new_global, new_state = strategy.server_update(
-            avg_params, global_params, server_state, rnd
-        )
+        with jax.named_scope("fl.reduce"):
+            avg_params = _pin(jax.tree.map(
+                lambda g, d: (g.astype(jnp.float32) + d.astype(jnp.float32)).astype(g.dtype),
+                global_params, delta,
+            ))
+        with jax.named_scope("fl.server_update"):
+            new_global, new_state = strategy.server_update(
+                avg_params, global_params, server_state, rnd
+            )
         metrics = {
             "client_loss_mean": loss_mean,
             "client_loss_max": loss_max,

@@ -32,6 +32,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+import jax
 import numpy as np
 
 from repro.utils.logging import MetricsLogger
@@ -199,168 +200,174 @@ class Server:
 
         pending: list[Arrival] = []  # in-flight arrivals (BufferedAsync carry)
         for rnd in range(1, num_rounds + 1):
-            # ---- dispatch: sampled ∩ available ∩ not already in flight ----
-            busy = {a.client_id for a in pending}
-            if pop is not None:
-                # cohort first, availability streamed over candidates only
-                # (inside sample_cohort) — then per-cohort properties and
-                # per-dispatch streamed jitter: all O(cohort), never O(N)
-                eligible = self.strategy.sample_cohort(
-                    rnd, pop, self.cohort_size, exclude=busy,
-                    availability=self.availability,
-                    cost_model=self.cost_model, deadline_s=deadline_cfg,
-                )
-                # heavy churn can leave the bounded redraw short — or empty.
-                # A short/empty cohort follows the legacy empty-round path
-                # below: zero dispatches, the policy still advances the
-                # clock, nothing aggregates, the round records participants=0
-                # with NaN train_loss (pinned by tests/test_population.py
-                # ::test_forced_churn_short_and_empty_cohorts)
-                client_props = {
-                    cid: self.clients[cid].properties() for cid in eligible
-                }
-                jitter = None
-            else:
-                # one trace draw per round (it is a deterministic function
-                # of (seed, rnd)), not one full-fleet draw per client
-                up = (
-                    self.availability.available(rnd)
-                    if self.availability is not None else None
-                )
-                eligible = [
-                    cid for cid in client_ids
-                    if cid not in busy and (up is None or up[cid])
-                ]
-                jitter = (
-                    self.availability.step_jitter(rnd)
-                    if self.availability is not None else None
-                )
-            fit_ins = self.strategy.configure_fit(
-                rnd, global_params, eligible, client_properties=client_props
-            ) if eligible else []
-            jitter_by_cid = {}
-            if pop is not None and self.availability is not None and fit_ins:
-                cids = [cid for cid, _ in fit_ins]
-                jitter_by_cid = dict(zip(
-                    cids, self.availability.step_jitter_for(rnd, cids).tolist()
-                ))
-
-            launch_steps = 0
-            for cid, ins in fit_ins:
-                if deadline_cfg is not None:
-                    ins.config.setdefault("deadline_s", deadline_cfg)
-                res = self.clients[cid].fit(ins)
-                steps = int(res.metrics.get("steps_done", 1))
-                launch_steps += steps
-                cost = None
-                up_bytes = self._uplink_bytes_one(res, cid, uplink_fallback)
-                if self.cost_model is not None:
-                    if jitter is not None:
-                        jit_c = float(jitter[cid])
-                    else:
-                        jit_c = float(jitter_by_cid.get(cid, 1.0))
-                    cost = self.cost_model.client_round_cost(
-                        cid, steps, uplink_bytes=up_bytes, jitter=jit_c,
+            with jax.profiler.StepTraceAnnotation("fl.round", step_num=rnd):
+                # ---- dispatch: sampled ∩ available ∩ not already in flight ----
+                busy = {a.client_id for a in pending}
+                if pop is not None:
+                    # cohort first, availability streamed over candidates only
+                    # (inside sample_cohort) — then per-cohort properties and
+                    # per-dispatch streamed jitter: all O(cohort), never O(N)
+                    eligible = self.strategy.sample_cohort(
+                        rnd, pop, self.cohort_size, exclude=busy,
+                        availability=self.availability,
+                        cost_model=self.cost_model, deadline_s=deadline_cfg,
                     )
-                    # the cost record owns the arrival time; the scheduler
-                    # event (Arrival.finish_t) is derived from it below
-                    cost.t_arrival_s = clock.now + cost.t_total_s
-                # keep the launch global only when a stale rebase could need
-                # it: compressed payloads are deltas (global-independent), so
-                # pinning a full model snapshot per in-flight arrival would
-                # be O(pending x model) of provably dead memory
-                launch_ref = (
-                    None if isinstance(res.parameters, CompressedParameters)
-                    else global_params
+                    # heavy churn can leave the bounded redraw short — or empty.
+                    # A short/empty cohort follows the legacy empty-round path
+                    # below: zero dispatches, the policy still advances the
+                    # clock, nothing aggregates, the round records participants=0
+                    # with NaN train_loss (pinned by tests/test_population.py
+                    # ::test_forced_churn_short_and_empty_cohorts)
+                    client_props = {
+                        cid: self.clients[cid].properties() for cid in eligible
+                    }
+                    jitter = None
+                else:
+                    # one trace draw per round (it is a deterministic function
+                    # of (seed, rnd)), not one full-fleet draw per client
+                    up = (
+                        self.availability.available(rnd)
+                        if self.availability is not None else None
+                    )
+                    eligible = [
+                        cid for cid in client_ids
+                        if cid not in busy and (up is None or up[cid])
+                    ]
+                    jitter = (
+                        self.availability.step_jitter(rnd)
+                        if self.availability is not None else None
+                    )
+                with jax.profiler.TraceAnnotation("fl.configure_fit"):
+                    fit_ins = self.strategy.configure_fit(
+                        rnd, global_params, eligible, client_properties=client_props
+                    ) if eligible else []
+                jitter_by_cid = {}
+                if pop is not None and self.availability is not None and fit_ins:
+                    cids = [cid for cid, _ in fit_ins]
+                    jitter_by_cid = dict(zip(
+                        cids, self.availability.step_jitter_for(rnd, cids).tolist()
+                    ))
+
+                launch_steps = 0
+                for cid, ins in fit_ins:
+                    if deadline_cfg is not None:
+                        ins.config.setdefault("deadline_s", deadline_cfg)
+                    with jax.profiler.TraceAnnotation("fl.fit"):
+                        res = self.clients[cid].fit(ins)
+                    steps = int(res.metrics.get("steps_done", 1))
+                    launch_steps += steps
+                    cost = None
+                    up_bytes = self._uplink_bytes_one(res, cid, uplink_fallback)
+                    if self.cost_model is not None:
+                        if jitter is not None:
+                            jit_c = float(jitter[cid])
+                        else:
+                            jit_c = float(jitter_by_cid.get(cid, 1.0))
+                        cost = self.cost_model.client_round_cost(
+                            cid, steps, uplink_bytes=up_bytes, jitter=jit_c,
+                        )
+                        # the cost record owns the arrival time; the scheduler
+                        # event (Arrival.finish_t) is derived from it below
+                        cost.t_arrival_s = clock.now + cost.t_total_s
+                    # keep the launch global only when a stale rebase could need
+                    # it: compressed payloads are deltas (global-independent), so
+                    # pinning a full model snapshot per in-flight arrival would
+                    # be O(pending x model) of provably dead memory
+                    launch_ref = (
+                        None if isinstance(res.parameters, CompressedParameters)
+                        else global_params
+                    )
+                    pending.append(Arrival(
+                        client_id=cid, launch_rnd=rnd, launch_t=clock.now,
+                        finish_t=cost.t_arrival_s if cost is not None else clock.now,
+                        cost=cost, payload=(res, launch_ref), uplink_bytes=up_bytes,
+                    ))
+
+                # ---- the policy's verdict on everything in flight ----
+                with jax.profiler.TraceAnnotation("fl.policy"):
+                    outcome = policy.plan(clock, pending, rnd, strategy=self.strategy)
+                    pending = list(outcome.carried)
+                    clock.advance_to(outcome.round_end)
+
+                    # a discarded update never reached the aggregate: the client
+                    # must roll back any state (error-feedback residual) that its
+                    # fit() committed assuming delivery — the python-path twin of
+                    # the jitted mask's carry-residual-unchanged contract
+                    for a in (*outcome.dropped, *outcome.expired):
+                        self.clients[a.client_id].discard_update()
+
+                results = []
+                for a in outcome.reported:
+                    res, launch_global = a.payload
+                    res.staleness = a.staleness_at(rnd)
+                    if res.staleness > 0:
+                        self._rebase_stale(res, launch_global, global_params)
+                    results.append((a.client_id, res))
+
+                if results:  # an empty round advances the clock, aggregates nothing
+                    with jax.profiler.TraceAnnotation("fl.aggregate_fit"):
+                        global_params = self.strategy.aggregate_fit(
+                            rnd, results, global_params
+                        )
+
+                # ---- system-cost accounting (the paper's §5 measurement) ----
+                # wall time is the clock's elapsed virtual time for this round;
+                # idle burn charges the actual wait each reporter endured; a
+                # deadline-dropped client charges its (wasted) compute up to the
+                # cutoff; uplink is charged at each reporter's wire size while
+                # the downlink stays the full-precision global per dispatch.
+                wall, energy, comm = outcome.wall_time_s, 0.0, 0
+                if self.cost_model is not None:
+                    down = self.cost_model.update_bytes
+                    energy = self._outcome_energy(outcome)
+                    # expired arrivals that LANDED did cross the network (they
+                    # arrived, then aged out) — their bytes count like their
+                    # comm energy does; cancelled-in-flight expiries and
+                    # deadline-dropped clients never completed an uplink
+                    comm = down * len(fit_ins) + sum(
+                        down if a.uplink_bytes is None else a.uplink_bytes
+                        for a in (*outcome.reported, *outcome.expired)
+                        if a.finish_t <= outcome.round_end
+                    )
+
+                losses = [r.metrics.get("loss", 0.0) for _, r in results]
+                ns = [r.num_examples for _, r in results]
+                # all-zero example counts (empty shards / failed reads) must not
+                # crash np.average with a ZeroDivisionError: unweighted fallback;
+                # an empty round has no losses at all -> NaN, not a crash
+                if not losses:
+                    train_loss = float("nan")
+                else:
+                    train_loss = float(
+                        np.average(losses, weights=ns) if sum(ns) > 0 else np.mean(losses)
+                    )
+
+                eval_loss = eval_acc = None
+                if rnd % self.eval_every == 0:
+                    # population mode restricts eval_fn-less federated eval to
+                    # the round's cohort: evaluating N clients would be the
+                    # O(N) loop this mode exists to avoid
+                    with jax.profiler.TraceAnnotation("fl.evaluate"):
+                        eval_loss, eval_acc = self._evaluate(
+                            global_params,
+                            eval_ids=eligible if pop is not None else None,
+                        )
+
+                rec = RoundRecord(
+                    rnd=rnd, train_loss=train_loss, eval_loss=eval_loss,
+                    eval_acc=eval_acc, wall_time_s=wall, energy_j=energy,
+                    comm_bytes=comm, steps=launch_steps,
+                    participants=len(results),
+                    dropped=len(outcome.dropped) + len(outcome.expired),
+                    staleness_mean=outcome.mean_staleness,
                 )
-                pending.append(Arrival(
-                    client_id=cid, launch_rnd=rnd, launch_t=clock.now,
-                    finish_t=cost.t_arrival_s if cost is not None else clock.now,
-                    cost=cost, payload=(res, launch_ref), uplink_bytes=up_bytes,
-                ))
-
-            # ---- the policy's verdict on everything in flight ----
-            outcome = policy.plan(clock, pending, rnd, strategy=self.strategy)
-            pending = list(outcome.carried)
-            clock.advance_to(outcome.round_end)
-
-            # a discarded update never reached the aggregate: the client must
-            # roll back any state (error-feedback residual) that its fit()
-            # committed assuming delivery — the python-path twin of the
-            # jitted mask's carry-residual-unchanged contract
-            for a in (*outcome.dropped, *outcome.expired):
-                self.clients[a.client_id].discard_update()
-
-            results = []
-            for a in outcome.reported:
-                res, launch_global = a.payload
-                res.staleness = a.staleness_at(rnd)
-                if res.staleness > 0:
-                    self._rebase_stale(res, launch_global, global_params)
-                results.append((a.client_id, res))
-
-            if results:  # an empty round advances the clock, aggregates nothing
-                global_params = self.strategy.aggregate_fit(
-                    rnd, results, global_params
+                history.add(rec)
+                self.logger.log(
+                    "round", rnd=rnd, loss=train_loss,
+                    acc=-1.0 if eval_acc is None else eval_acc,
+                    wall_s=wall, energy_kj=energy / 1e3,
+                    clients=len(results), stale=outcome.mean_staleness,
                 )
-
-            # ---- system-cost accounting (the paper's §5 measurement) ----
-            # wall time is the clock's elapsed virtual time for this round;
-            # idle burn charges the actual wait each reporter endured; a
-            # deadline-dropped client charges its (wasted) compute up to the
-            # cutoff; uplink is charged at each reporter's wire size while
-            # the downlink stays the full-precision global per dispatch.
-            wall, energy, comm = outcome.wall_time_s, 0.0, 0
-            if self.cost_model is not None:
-                down = self.cost_model.update_bytes
-                energy = self._outcome_energy(outcome)
-                # expired arrivals that LANDED did cross the network (they
-                # arrived, then aged out) — their bytes count like their
-                # comm energy does; cancelled-in-flight expiries and
-                # deadline-dropped clients never completed an uplink
-                comm = down * len(fit_ins) + sum(
-                    down if a.uplink_bytes is None else a.uplink_bytes
-                    for a in (*outcome.reported, *outcome.expired)
-                    if a.finish_t <= outcome.round_end
-                )
-
-            losses = [r.metrics.get("loss", 0.0) for _, r in results]
-            ns = [r.num_examples for _, r in results]
-            # all-zero example counts (empty shards / failed reads) must not
-            # crash np.average with a ZeroDivisionError: unweighted fallback;
-            # an empty round has no losses at all -> NaN, not a crash
-            if not losses:
-                train_loss = float("nan")
-            else:
-                train_loss = float(
-                    np.average(losses, weights=ns) if sum(ns) > 0 else np.mean(losses)
-                )
-
-            eval_loss = eval_acc = None
-            if rnd % self.eval_every == 0:
-                # population mode restricts eval_fn-less federated eval to
-                # the round's cohort: evaluating N clients would be the
-                # O(N) loop this mode exists to avoid
-                eval_loss, eval_acc = self._evaluate(
-                    global_params,
-                    eval_ids=eligible if pop is not None else None,
-                )
-
-            rec = RoundRecord(
-                rnd=rnd, train_loss=train_loss, eval_loss=eval_loss,
-                eval_acc=eval_acc, wall_time_s=wall, energy_j=energy,
-                comm_bytes=comm, steps=launch_steps,
-                participants=len(results),
-                dropped=len(outcome.dropped) + len(outcome.expired),
-                staleness_mean=outcome.mean_staleness,
-            )
-            history.add(rec)
-            self.logger.log(
-                "round", rnd=rnd, loss=train_loss,
-                acc=-1.0 if eval_acc is None else eval_acc,
-                wall_s=wall, energy_kj=energy / 1e3,
-                clients=len(results), stale=outcome.mean_staleness,
-            )
 
         # arrivals still in flight when the run ends are abandoned: their
         # clients roll back (the update never landed), and the wasted work
@@ -423,7 +430,6 @@ class Server:
         ``participation_mask``/``dispatch_mask``/``round_wall_s``/
         ``participants``/``dispatched``).
         """
-        import jax
         import jax.numpy as jnp
 
         from repro.utils.pytree import tree_size as _tree_size
@@ -460,7 +466,8 @@ class Server:
             else jnp.asarray(step_budgets, jnp.int32)
         )
         n_params = _tree_size(global_params)
-        sched = self._scan_schedule(spec, R, C, np.asarray(bud), n_params)
+        with jax.profiler.TraceAnnotation("fl.scan.schedule"):
+            sched = self._scan_schedule(spec, R, C, np.asarray(bud), n_params)
         avail = jnp.asarray(sched["avail"])
         t_verdict = jnp.asarray(sched["t_verdict"])
         pri = jnp.asarray(sched["pri"])
@@ -494,15 +501,17 @@ class Server:
                 self._scan_fns[key] = (fn, (loss_fn, opt, trainable_mask))
             else:
                 fn = cached[0]
-            if donate:
-                # donated buffers alias in-place across the scan carry —
-                # copy first so the CALLER's arrays stay valid
-                global_params = jax.tree.map(jnp.array, global_params)
-            g, _, _, stacked = fn(
-                global_params, server_state, client_state, batches, w, bud,
-                avail, t_verdict, pri,
-            )
-            stacked = jax.device_get(stacked)
+            with jax.profiler.TraceAnnotation("fl.scan.run"):
+                if donate:
+                    # donated buffers alias in-place across the scan carry —
+                    # copy first so the CALLER's arrays stay valid
+                    global_params = jax.tree.map(jnp.array, global_params)
+                g, _, _, stacked = fn(
+                    global_params, server_state, client_state, batches, w, bud,
+                    avail, t_verdict, pri,
+                )
+            with jax.profiler.TraceAnnotation("fl.scan.fetch"):
+                stacked = jax.device_get(stacked)
         else:
             if cached is None:
                 round_step = jax.jit(make_round_step(
@@ -551,9 +560,10 @@ class Server:
         eval_final = (
             self._evaluate(g) if self.eval_fn is not None else None
         )
-        history = self._decode_scan_history(
-            stacked, sched, np.asarray(bud), eval_final
-        )
+        with jax.profiler.TraceAnnotation("fl.scan.history"):
+            history = self._decode_scan_history(
+                stacked, sched, np.asarray(bud), eval_final
+            )
         self.logger.log(
             "scanned", rounds=R, driver="python" if reference else "scan",
             loss=history.rounds[-1].train_loss if history.rounds else -1.0,

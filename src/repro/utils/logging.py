@@ -1,36 +1,23 @@
 """Minimal structured logging for the FL server and launchers."""
 from __future__ import annotations
 
-import json
 import sys
-import time
 from dataclasses import dataclass, field
 from typing import Any
 
 
 @dataclass
 class MetricsLogger:
-    """Collects per-round metrics; prints compact lines and can dump JSON."""
+    """Prints one compact line per event, unless ``quiet``."""
 
     name: str = "repro"
     stream: Any = field(default_factory=lambda: sys.stderr)
-    history: list[dict] = field(default_factory=list)
-    t0: float = field(default_factory=time.time)
     quiet: bool = False
 
     def log(self, event: str, **kv) -> None:
-        rec = {"t": round(time.time() - self.t0, 3), "event": event, **kv}
-        self.history.append(rec)
         if not self.quiet:
             kvs = " ".join(
                 f"{k}={v:.4f}" if isinstance(v, float) else f"{k}={v}"
                 for k, v in kv.items()
             )
             print(f"[{self.name}] {event} {kvs}", file=self.stream)
-
-    def dump(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.history, f, indent=1, default=str)
-
-    def series(self, event: str, key: str) -> list:
-        return [r[key] for r in self.history if r["event"] == event and key in r]

@@ -17,7 +17,6 @@ import pytest
 @pytest.mark.parametrize("mod", [
     "benchmarks.run",
     "benchmarks.paper_tables",
-    "benchmarks.roofline_report",
     "benchmarks.scan_bench",
     "benchmarks.mesh_bench",
     "benchmarks.compression_bench",
@@ -79,22 +78,3 @@ def test_paper_tables_one_cell():
     assert label == "E=1"
     assert 0.0 <= acc <= 1.0
     assert mins > 0 and kj > 0
-
-
-def test_roofline_render_matches_dryrun_fields(tmp_path):
-    """The report reads exactly the field names dryrun emits; a renamed
-    field shows up here as a KeyError instead of a broken EXPERIMENTS.md."""
-    from benchmarks.roofline_report import render
-
-    row = {
-        "arch": "qwen3-0.6b", "shape": "train_4k", "mesh": "16x16",
-        "per_device_gb": 3.21, "compute_ms": 12.5, "memory_ms": 4.2,
-        "collective_ms": 1.7, "dominant": "compute",
-        "useful_flops_frac": 0.61,
-    }
-    path = tmp_path / "dryrun_results.json"
-    path.write_text(json.dumps([row]))
-    table = render(str(path))
-    assert "| qwen3-0.6b | train_4k | 3.21 | 12.5 | 4.2 | 1.7 | compute | 0.61 |" in table
-    # missing cells render as pending, not crash
-    assert "(pending)" in table
