@@ -86,9 +86,11 @@ The O(C·k) TopK reduce contract
 
 - **Payload layout**: per client, ``idx`` (k,) int32 positions and ``val``
   (k,) fp32 values, 8k wire bytes.  The encoder is deterministic (equal
-  magnitudes tie-break toward the lower index via a stable sort) and emits
-  indices in canonical ascending order, so a given delta yields
-  bit-identical wire bytes under jit and eager alike.
+  magnitudes tie-break toward the lower index) and emits indices in
+  canonical ascending order, so a given delta yields bit-identical wire
+  bytes under jit and eager alike.  It selects by an exact magnitude
+  threshold found in counting passes over the row, never by a sort
+  (``ops.topk_select_rows``, every segment's rows in one call).
 - **Duplicate-index semantics**: our encoder emits distinct indices, but
   every consumer (``decode``, ``decode_batch``, ``reduce``, the Pallas
   kernel and its oracle) treats duplicates as scatter-ADD — a foreign
@@ -418,23 +420,19 @@ class UpdateCodec:
 
         segs = self.segment_map()
         leaves_g, treedef = jax.tree_util.tree_flatten(global_params)
-        new_state = list(state)
         if segs.matches_leaves(leaves_g):
             leaves_c = jax.tree_util.tree_flatten(client_params)[0]
-            new_leaves = []
-            for i, (seg, lc, lg) in enumerate(zip(segs, leaves_c, leaves_g)):
-                c = lc.shape[0]
-                block = (
-                    lc.astype(jnp.float32).reshape(c, -1)
-                    - lg.astype(jnp.float32).reshape(-1)
-                )
-                mean_i, new_state[i] = self.aggregate_segment_batch(
-                    block, weights, state[i], seg
-                )
-                new_leaves.append(
-                    (lg.astype(jnp.float32) + mean_i.reshape(lg.shape)).astype(lg.dtype)
-                )
-            return jax.tree_util.tree_unflatten(treedef, new_leaves), tuple(new_state)
+            blocks = [
+                lc.astype(jnp.float32).reshape(lc.shape[0], -1)
+                - lg.astype(jnp.float32).reshape(-1)
+                for lc, lg in zip(leaves_c, leaves_g)
+            ]
+            means, new_state = self.aggregate_segment_blocks(blocks, weights, state, segs)
+            new_leaves = [
+                (lg.astype(jnp.float32) + m.reshape(lg.shape)).astype(lg.dtype)
+                for m, lg in zip(means, leaves_g)
+            ]
+            return jax.tree_util.tree_unflatten(treedef, new_leaves), new_state
 
         flat_global = tree_flatten_to_vector(global_params)
         self.segment_map(flat_global.shape[0])
@@ -460,13 +458,11 @@ class UpdateCodec:
         if self.segments is None:
             return self._aggregate_batch_flat(deltas, weights, state)
         segs = self.segment_map(deltas.shape[1])
-        parts, new_state = [], list(state)
-        for i, seg in enumerate(segs):
-            part, new_state[i] = self.aggregate_segment_batch(
-                deltas[:, seg.offset : seg.offset + seg.size], weights, state[i], seg
-            )
-            parts.append(part)
-        return jnp.concatenate(parts), tuple(new_state)
+        parts, new_state = self.aggregate_segment_blocks(
+            [deltas[:, seg.offset : seg.offset + seg.size] for seg in segs],
+            weights, state, segs,
+        )
+        return jnp.concatenate(parts), new_state
 
     def _aggregate_batch_flat(self, deltas, weights, state):
         with jax.named_scope("fl.encode"):
@@ -474,6 +470,15 @@ class UpdateCodec:
             enc = self.encode_batch(eff)
             new_state = eff - self.decode_batch(enc)
         return self.reduce(enc, weights), new_state
+
+    def aggregate_segment_blocks(self, blocks, weights, state, segs: SegmentMap):
+        """Every segment's (C, seg.size) block -> (means, new state tuple):
+        by default each segment on its own (``aggregate_segment_batch``)."""
+        out = [
+            self.aggregate_segment_batch(b, weights, row, seg)
+            for b, row, seg in zip(blocks, state, segs)
+        ]
+        return [m for m, _ in out], tuple(row for _, row in out)
 
     def aggregate_segment_batch(self, deltas, weights, state, seg: Segment):
         """One segment's (C, seg.size) block -> (mean (seg.size,), new state).
@@ -732,9 +737,10 @@ class TopKCodec(UpdateCodec):
     Wire contract (load-bearing for the O(C·k) reduce):
 
     - selection is DETERMINISTIC: magnitudes tie-break toward the lower
-      index via a stable sort (raw ``lax.top_k`` tie order is lowering-
-      dependent), so a given delta produces bit-identical payloads under
-      jit and eager alike;
+      index (raw ``lax.top_k`` tie order is lowering-dependent), so a given
+      delta produces bit-identical payloads under jit and eager alike; the
+      k are found by an exact magnitude threshold (``ops.topk_select_rows``),
+      a few counting passes over the row instead of a sort of it;
     - payload indices are canonically sorted ascending — reproducible wire
       bytes, and the scatter kernel walks VMEM monotonically;
     - this encoder emits distinct indices, but every consumer treats
@@ -761,31 +767,25 @@ class TopKCodec(UpdateCodec):
     def _wire_bytes_scalar(self, n_params: int) -> int:
         return self.k_of(n_params) * 8  # int32 index + fp32 value
 
-    @staticmethod
-    def _topk_idx(mags: jnp.ndarray, k: int) -> jnp.ndarray:
-        """Deterministic top-k positions along the last axis: stable sort by
-        descending magnitude (ties keep ascending index order), then the
-        selected k re-sorted to the canonical ascending-index wire order."""
-        iota = jax.lax.broadcasted_iota(jnp.int32, mags.shape, mags.ndim - 1)
-        _, idx = jax.lax.sort(
-            (-mags.astype(jnp.float32), iota),
-            dimension=-1, num_keys=1, is_stable=True,
-        )
-        return jnp.sort(idx[..., :k], axis=-1)
+    def _encode_residual(self, *effs: jnp.ndarray):
+        """Leaves (..., n) -> [(payload, the leaf with the transmitted
+        coordinates zeroed: the error-feedback residual)], every leaf's
+        selection in one ``ops.topk_select_rows`` call."""
+        ks = [self.k_of(e.shape[-1]) for e in effs]
+        return [
+            ({"idx": idx, "val": val, "n": e.shape[-1]}, residual)
+            for e, (idx, val, residual) in zip(effs, ops.topk_select_rows(effs, ks))
+        ]
 
     def encode(self, delta_vec: jnp.ndarray):
-        n = delta_vec.shape[0]
-        idx = self._topk_idx(jnp.abs(delta_vec), self.k_of(n))
-        return {"idx": idx, "val": delta_vec[idx], "n": n}
+        return self._encode_residual(delta_vec)[0][0]
 
     def decode(self, enc) -> jnp.ndarray:
         # scatter-ADD: duplicate indices accumulate (kernel semantics)
         return jnp.zeros((enc["n"],), enc["val"].dtype).at[enc["idx"]].add(enc["val"])
 
     def encode_batch(self, deltas: jnp.ndarray):
-        n = deltas.shape[1]
-        idx = self._topk_idx(jnp.abs(deltas), self.k_of(n))  # (C, k)
-        return {"idx": idx, "val": jnp.take_along_axis(deltas, idx, axis=1), "n": n}
+        return self._encode_residual(deltas)[0][0]  # idx, val: (C, k)
 
     def decode_batch(self, enc) -> jnp.ndarray:
         """Densify fallback: the dense (C, n) matrix for callers that want
@@ -804,19 +804,21 @@ class TopKCodec(UpdateCodec):
         feedback state — TopK transmits exact values, so
         ``eff - decode(enc) == eff`` zeroed at idx; no dense decode."""
         with jax.named_scope("fl.encode"):
-            eff = deltas + state
-            enc = self.encode_batch(eff)
-            rows = jnp.arange(eff.shape[0])[:, None]
-            new_state = eff.at[rows, enc["idx"]].set(0.0)
+            [(enc, new_state)] = self._encode_residual(deltas + state)
         return self.reduce(enc, weights), new_state
+
+    def aggregate_segment_blocks(self, blocks, weights, state, segs: SegmentMap):
+        """Every segment's selection in one pass, then each segment's
+        scatter reduce: one selection kernel for the whole model."""
+        with jax.named_scope("fl.encode"):
+            sent = self._encode_residual(*(b + row for b, row in zip(blocks, state)))
+        return [self.reduce(enc, weights) for enc, _ in sent], tuple(r for _, r in sent)
 
     def transmit_segment(self, vec: jnp.ndarray, state_row, seg: Segment):
         """Per-client path (mesh shard_map / sequential scan): the decode
         stays per-client (seg.size,) — never (C, N) — and the next state
-        row zeroes the transmitted coordinates in O(k)."""
-        eff = vec + state_row
-        enc = self.encode_segment(eff, seg)
-        new_row = eff.at[enc["idx"]].set(0.0)
+        row zeroes the transmitted coordinates."""
+        [(enc, new_row)] = self._encode_residual(vec + state_row)
         return self.decode_segment(enc, seg), new_row
 
     def reduce(self, enc, weights: jnp.ndarray, *, interpret: bool = False):

@@ -17,7 +17,7 @@ import importlib
 _SUBMODULES = (
     "collective_quant", "decode_attention", "dequant_reduce",
     "fedavg_reduce", "flash_attention", "ops", "quantize", "ref",
-    "scatter_reduce", "selective_scan",
+    "scatter_reduce", "selective_scan", "topk_select",
 )
 
 

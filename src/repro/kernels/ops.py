@@ -180,6 +180,24 @@ def topk_scatter_reduce(idx, val, weights, n_params: int, *, interpret=False,
     return out if normalize else _denormalize(out, weights)
 
 
+def topk_select_rows(xs, ks, *, interpret=False):
+    """The TopK encoder's selection, for every leaf of an update at once:
+    leaves ``xs[i]`` (..., n_i), one leading shape, and their ``ks[i]`` ->
+    a list of (idx (..., k_i) int32, the k_i largest |x| of each row in
+    ascending index order, equal magnitudes to the lower index; val =
+    x[idx]; x with those coordinates zeroed).  Both branches find the k by
+    an exact magnitude threshold and agree bit for bit; the kernel compacts
+    every leaf in one call and one pass, where the oracle binary-searches a
+    running count leaf by leaf."""
+    xs, ks = tuple(xs), tuple(ks)
+    if (_use_pallas() or interpret) and all(x.dtype == jnp.float32 for x in xs):
+        from .topk_select import MAX_K, topk_select_rows as ts
+
+        if sum(ks) <= MAX_K:
+            return ts(xs, ks, interpret=interpret or jax.default_backend() != "tpu")
+    return [ref.topk_select(x, k) for x, k in zip(xs, ks)]
+
+
 # ---------------- int8 codec ----------------
 # The int8 kernels take any (..., N) with N % block == 0 (the encoders pad
 # to a block multiple) and tile-pad beyond it themselves: no shape gate.

@@ -6,6 +6,8 @@ lowers these; XLA counts identical matmul FLOPs).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -344,3 +346,79 @@ def dequant_reduce(
     acc = jnp.einsum("c,cn->n", wf, x.reshape(c, n),
                      precision=jax.lax.Precision.HIGHEST)
     return acc / safe_weight_sum(wf)
+
+
+# --------------------------------------------------------------------------
+# TopK selection: an exact magnitude threshold, no sort
+# --------------------------------------------------------------------------
+TOPK_PASS_BITS = 4  # threshold bits settled per counting pass: 15 probes a pass
+
+
+def topk_keys(x: jnp.ndarray) -> jnp.ndarray:
+    """int32 keys that order as |x| does: the fp32 bits of |x| plus one (up
+    to 0x7F800001 for inf), NaN 0, below every number; -0 keys as +0.
+    The order of a stable sort of -|x|."""
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32) & 0x7FFFFFFF
+    return jnp.where(bits > 0x7F800000, 0, bits + 1)
+
+
+@functools.partial(jax.jit, static_argnames="k")
+def topk_threshold(key: jnp.ndarray, k: int) -> jnp.ndarray:
+    """(C, ...) keys -> (C,) the k-th largest key of each row: the largest
+    t with count(key >= t) >= k.  Built from the top bit down,
+    ``TOPK_PASS_BITS`` bits a pass; each pass counts the row against its
+    2**TOPK_PASS_BITS - 1 probes in one fused reduction.  Jitted, with the
+    passes in one unrolled loop, so that a model's leaves of one shape
+    trace and lower it once."""
+    axes = tuple(range(1, key.ndim))
+
+    def settle(t, low, width):
+        # counts fall as the probe rises, so the number of probes that still
+        # reach k is the highest step of these bits that t can take
+        reach = [
+            jnp.sum(key >= t + (j << low), axis=axes, keepdims=True, dtype=jnp.int32) >= k
+            for j in range(1, 1 << width)
+        ]
+        return t + (sum(r.astype(jnp.int32) for r in reach) << low)
+
+    # keys < 2**31: bits 30..0 to settle, the top ones first
+    passes, top = divmod(31, TOPK_PASS_BITS)
+    t = jnp.zeros(key.shape[:1] + (1,) * len(axes), jnp.int32)
+    t = settle(t, TOPK_PASS_BITS * passes, top)
+    t = jax.lax.fori_loop(
+        0, passes,
+        lambda i, t: settle(t, TOPK_PASS_BITS * (passes - 1 - i), TOPK_PASS_BITS),
+        t, unroll=True,
+    )
+    return t.reshape(key.shape[:1])
+
+
+def topk_select(x: jnp.ndarray, k: int):
+    """(..., n) -> (idx (..., k) int32, val (..., k), residual (..., n)): the
+    k largest |x| of each row, equal magnitudes to the lower index, their
+    positions ascending; ``val = x[idx]`` and ``residual`` is ``x`` with
+    them zeroed.  Ties: of the keys equal to the threshold, the first
+    ``k - count(key > t)`` in index order (an inclusive cumsum).  The j-th
+    kept position is the first whose running count of kept reaches j: a
+    binary search of that count for every j."""
+    lead, n = x.shape[:-1], x.shape[-1]
+    rows = x.reshape(-1, n)
+    key = topk_keys(rows)
+    t = topk_threshold(key, k)[:, None]
+    above, at = key > t, key == t
+    need = k - jnp.sum(above, axis=1, keepdims=True, dtype=jnp.int32)
+    keep = above | (at & (jnp.cumsum(at, axis=1, dtype=jnp.int32) <= need))
+
+    kept = jnp.cumsum(keep, axis=1, dtype=jnp.int32)  # kept[:, -1] == k
+    target = jax.lax.broadcasted_iota(jnp.int32, (rows.shape[0], k), 1) + 1
+    idx = jnp.zeros_like(target)  # grows to the count of positions with kept < target
+    step = 1 << (n.bit_length() - 1)
+    while step:
+        probe = idx + step
+        at_probe = jnp.minimum(probe, n) - 1
+        below = jnp.take_along_axis(kept, at_probe, axis=1, mode="promise_in_bounds") < target
+        idx = jnp.where((probe <= n) & below, probe, idx)
+        step >>= 1
+    val = jnp.take_along_axis(rows, idx, axis=1)
+    residual = jnp.where(keep, jnp.zeros((), x.dtype), rows)
+    return idx.reshape(lead + (k,)), val.reshape(lead + (k,)), residual.reshape(x.shape)

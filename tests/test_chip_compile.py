@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import (
     collective_quant, dequant_reduce, fedavg_reduce, flash_attention,
-    quantize, scatter_reduce,
+    quantize, scatter_reduce, topk_select,
 )
 
 C = 8
@@ -93,6 +93,13 @@ CASES = {
         lambda i, v, w: scatter_reduce.topk_scatter_reduce(
             i, v, w, scatter_reduce.MAX_N_PARAMS - 100),
         [((C, 1000), I32), ((C, 1000), F32), ((C,), F32)]),
+    # the TopK encoder's selection: the largest leaf at 1%, alone and with
+    # a leaf shorter than a line in one call
+    "topk_select": (
+        lambda x: topk_select.topk_select_rows((x,), (LEAF // 100,)), [((C, LEAF), F32)]),
+    "topk_select_rows": (
+        lambda x, y: topk_select.topk_select_rows((x, y), (LEAF // 100, 1)),
+        [((C, LEAF), F32), ((C, 64), F32)]),
     # qwen3-0.6b attention: 16 query heads over 8 kv heads of width 128
     "flash_attention": (
         lambda q, k, v: flash_attention.flash_attention(q, k, v, causal=True),

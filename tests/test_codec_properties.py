@@ -13,7 +13,11 @@ proven here by properties rather than hand-picked examples, for Null / Int8
   its provable bound;
 - **TopK determinism**: equal-magnitude ties break toward the lower index,
   payloads are bit-identical under jit vs eager, and indices arrive in
-  canonical ascending order (regression for the lax.top_k tie order).
+  canonical ascending order (regression for the lax.top_k tie order);
+- **TopK selection against a stable-sort oracle**: the threshold selection
+  returns, bit for bit, the payload and residual of a stable sort of
+  ``-|x|`` (ties, signed zeros, inf and NaN, k=1, k=n, n=1, odd sizes, a
+  segmented tree), and the lowered encoder holds no sort at all.
 
 Hypothesis drives the randomized sweeps when installed (the CI ``test``
 extra); every property is ALSO pinned by seeded deterministic cases below
@@ -25,9 +29,10 @@ import numpy as np
 import pytest
 from hypothesis_compat import given, settings, st
 
-from repro.core import Int8Codec, NullCodec, TopKCodec
+from repro.core import Int8Codec, NullCodec, SegmentMap, TopKCodec
 from repro.core.protocol import compress_to_wire, wire_to_pytree
 from repro.core.compression import compress_update, decompress_update
+from repro.kernels import ops
 
 CODECS = {
     "null": NullCodec(),
@@ -207,6 +212,170 @@ def test_topk_keeps_largest_magnitudes():
     np.testing.assert_allclose(
         np.asarray(dec[enc["idx"]]), np.asarray(vec[enc["idx"]]), atol=0
     )
+
+
+# ------------------------------------------- TopK against a stable sort
+def _sort_oracle_idx(mags, k):
+    """The encoder's former selection, kept here as the oracle: a stable
+    sort of (-|x|, iota) and the first k re-sorted ascending."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, mags.shape, mags.ndim - 1)
+    _, idx = jax.lax.sort(
+        (-mags.astype(jnp.float32), iota), dimension=-1, num_keys=1, is_stable=True,
+    )
+    return jnp.sort(idx[..., :k], axis=-1)
+
+
+def _oracle_encode(x, k):
+    idx = _sort_oracle_idx(jnp.abs(x), k)
+    return idx, jnp.take_along_axis(x, idx, axis=-1)
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.int32) if a.dtype == np.float32 else a
+
+
+def _assert_bitwise(got, want, what):
+    np.testing.assert_array_equal(_bits(got), _bits(want), err_msg=what)
+
+
+def _selection_case(name):
+    """-> ((C, n) float32 rows, frac)."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    if name == "random":
+        return rng.normal(size=(10, 4099)).astype(np.float32) * 1e-3, 0.01
+    if name == "quantised_ties":
+        return (np.round(rng.normal(size=(3, 1000)) * 4) / 4).astype(np.float32), 0.1
+    if name == "all_equal_positive":
+        return np.full((2, 300), 0.5, np.float32), 0.1
+    if name == "all_equal_negative":
+        return np.full((2, 300), -0.5, np.float32), 0.1
+    if name == "half_zeroed":  # k reaches into the zeros, +0 and -0 tie
+        x = rng.normal(size=(4, 501)).astype(np.float32)
+        x[:, 1::2] = 0.0
+        x[:, 2::4] = -0.0
+        x[1] = -0.0
+        return x, 0.7
+    if name == "inf_nan":  # NaN ranks below every number, even 0
+        x = rng.normal(size=(4, 640)).astype(np.float32)
+        special = np.array([np.inf, -np.inf, np.nan, -np.nan, 0.0, -0.0, 1e-45], np.float32)
+        hit = rng.random(x.shape) < 0.4
+        x[hit] = rng.choice(special, size=int(hit.sum()))
+        return x, 0.9
+    if name == "mostly_nan":
+        x = rng.normal(size=(3, 200)).astype(np.float32)
+        x[rng.random(x.shape) < 0.8] = np.nan
+        return x, 0.5
+    if name == "k_is_1":
+        return rng.normal(size=(4, 777)).astype(np.float32), 0.0
+    if name == "k_is_n":
+        return (np.round(rng.normal(size=(3, 129)) * 2) / 2).astype(np.float32), 1.0
+    if name == "n_is_1":
+        return rng.normal(size=(3, 1)).astype(np.float32), 0.01
+    n = int(name.removeprefix("odd_n"))
+    return rng.normal(size=(5, n)).astype(np.float32) * 10.0 ** rng.uniform(-6, 6, (5, 1)), 0.05
+
+
+SELECTION_CASES = [
+    "random", "quantised_ties", "all_equal_positive", "all_equal_negative",
+    "half_zeroed", "inf_nan", "mostly_nan", "k_is_1", "k_is_n", "n_is_1",
+    "odd_n7", "odd_n65", "odd_n513", "odd_n12345",
+]
+
+
+@pytest.mark.parametrize("rank", [1, 2])
+@pytest.mark.parametrize("case", SELECTION_CASES)
+def test_topk_selection_matches_stable_sort_oracle(case, rank):
+    """Threshold selection == stable sort, bit for bit: idx, val and the
+    error-feedback residual, on the 1-D (per-client) and (C, n) surfaces."""
+    rows, frac = _selection_case(case)
+    codec = TopKCodec(frac=frac)
+    x = jnp.asarray(rows[0] if rank == 1 else rows)
+    n = x.shape[-1]
+    k = codec.k_of(n)
+    want_idx, want_val = _oracle_encode(x, k)
+
+    enc = jax.jit(codec.encode if rank == 1 else codec.encode_batch)(x)
+    _assert_bitwise(enc["idx"], want_idx, "idx")
+    _assert_bitwise(enc["val"], want_val, "val")
+    assert enc["n"] == n
+
+    # the residual: a state of -0.0 leaves eff = x + state bitwise x
+    state = jnp.full(x.shape, -0.0, jnp.float32)
+    if rank == 1:
+        seg = codec.segment_map(n)[0]
+        dec, resid = jax.jit(lambda v, r: codec.transmit_segment(v, r, seg))(x, state)
+        _assert_bitwise(dec, codec.decode({"idx": want_idx, "val": want_val, "n": n}), "decode")
+        _assert_bitwise(resid, x.at[want_idx].set(0.0), "residual")
+    else:
+        w = jnp.linspace(1.0, 2.0, x.shape[0], dtype=jnp.float32)
+        mean, resid = jax.jit(codec.aggregate_batch)(x, w, state)
+        rows_ = jnp.arange(x.shape[0])[:, None]
+        _assert_bitwise(resid, x.at[rows_, want_idx].set(0.0), "residual")
+        _assert_bitwise(mean, ops.topk_scatter_reduce(want_idx, want_val, w, n), "mean")
+
+
+def test_topk_selection_segmented_tree_matches_oracle():
+    """A ResNet-like tree, leaf by leaf through ``aggregate_updates``: new
+    params and every residual row equal the stable-sort oracle's."""
+    rng = np.random.default_rng(7)
+    shapes = {
+        "stem": {"kernel": (3, 3, 3, 16), "scale": (16,), "bias": (16,)},
+        "block": {"conv1": (3, 3, 16, 32), "conv2": (3, 3, 32, 32), "scale": (32,)},
+        "head": {"kernel": (32, 10), "bias": (10,)},
+    }
+    glob = jax.tree.map(
+        lambda s: jnp.asarray(rng.normal(size=s), jnp.float32), shapes,
+        is_leaf=lambda s: isinstance(s, tuple),
+    )
+    c = 4
+    clients = jax.tree.map(
+        lambda g: g + jnp.asarray(np.round(rng.normal(size=(c,) + g.shape) * 8) / 256, jnp.float32),
+        glob,
+    )
+    codec = TopKCodec(frac=0.05, segments=SegmentMap.from_tree(glob))
+    n_params = sum(g.size for g in jax.tree.leaves(glob))
+    state = tuple(
+        jnp.asarray(rng.normal(size=r.shape) * 1e-3, jnp.float32)
+        for r in codec.init_client_state(c, n_params)
+    )
+    w = jnp.asarray([1.0, 2.0, 0.5, 1.5], jnp.float32)
+    new_glob, new_state = jax.jit(codec.aggregate_updates)(clients, glob, w, state)
+
+    g_leaves, treedef = jax.tree.flatten(glob)
+    want_leaves, want_state = [], []
+    for seg, lc, lg, row in zip(codec.segments, jax.tree.leaves(clients), g_leaves, state):
+        eff = (lc.reshape(c, -1) - lg.reshape(-1)) + row
+        idx, val = _oracle_encode(eff, codec.k_of(seg.size))
+        want_state.append(eff.at[jnp.arange(c)[:, None], idx].set(0.0))
+        mean = ops.topk_scatter_reduce(idx, val, w, seg.size)
+        want_leaves.append(lg + mean.reshape(lg.shape))
+    for got, want in zip(jax.tree.leaves(new_glob), want_leaves):
+        _assert_bitwise(got, want, "params")
+    for got, want in zip(new_state, want_state):
+        _assert_bitwise(got, want, "residual")
+    assert jax.tree.structure(new_glob) == treedef
+
+
+@pytest.mark.parametrize("surface", ["encode_batch", "aggregate_batch"])
+def test_topk_encoder_lowers_without_sort(surface):
+    """At ResNet-18's largest leaf, (10, 3*3*512*512) fp32, the lowered
+    encoder (and the error-feedback aggregate around it) holds no sort, so
+    a fallback to sorting the leaf cannot come back silently.  Lowering
+    only: nothing compiles or allocates."""
+    c, n = 10, 3 * 3 * 512 * 512
+    codec = TopKCodec(frac=0.01)
+    rows = jax.ShapeDtypeStruct((c, n), jnp.float32)
+    if surface == "encode_batch":
+        lowered = jax.jit(codec.encode_batch).lower(rows)
+    else:
+        lowered = jax.jit(codec.aggregate_batch).lower(
+            rows, jax.ShapeDtypeStruct((c,), jnp.float32), rows
+        )
+    assert "stablehlo.sort" not in lowered.as_text(), f"{surface}: the program sorts"
+    # the check can see a sort: the former selection's lowers with one
+    oracle = jax.jit(lambda x: _sort_oracle_idx(jnp.abs(x), n // 100)).lower(rows)
+    assert "stablehlo.sort" in oracle.as_text()
 
 
 # ------------------------------------------------------- full client loop

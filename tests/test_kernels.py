@@ -13,6 +13,7 @@ from repro.kernels.fedavg_reduce import fedavg_reduce
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.quantize import dequantize_int8, quantize_int8
 from repro.kernels.selective_scan import selective_scan
+from repro.kernels.topk_select import BLOCK_LINES, topk_select_rows
 
 RNG = np.random.default_rng(0)
 
@@ -273,6 +274,69 @@ def test_topk_scatter_reduce_tail_indices(n):
     assert out.shape == (n,)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=1e-5, rtol=1e-5)
     assert np.asarray(out)[-1] == pytest.approx(float(exp[-1]), abs=1e-5)
+
+
+def _topk_rows(kind, c, n):
+    x = RNG.normal(size=(c, n)).astype(np.float32)
+    if kind == "ties":
+        x = np.round(x * 4) / 4
+    elif kind == "special":
+        special = np.array([np.nan, np.inf, -np.inf, 0.0, -0.0, 1e-45], np.float32)
+        hit = RNG.random(x.shape) < 0.4
+        x[hit] = RNG.choice(special, size=int(hit.sum()))
+    elif kind == "clustered":  # a run of large values: many kept in a line
+        x *= 1e-3
+        x[:, 300:300 + n // 20] *= 1e4
+    elif kind == "equal":
+        x[:] = -0.5
+    return jnp.asarray(x, jnp.float32)
+
+
+@pytest.mark.parametrize("kind,c,n,frac", [
+    ("random", 3, 4096, 0.01),
+    ("ties", 2, 1000, 0.1),            # n not a multiple of 128
+    ("special", 2, 777, 0.9),          # NaN, inf, signed zeros, denormals
+    ("clustered", 2, 70_000, 0.05),    # lines that keep most of their lanes
+    ("equal", 2, 9000, 0.5),           # every magnitude ties
+    ("random", 3, 64, 0.0),            # k = 1, a leaf shorter than a line
+    ("random", 1, 130, 1.0),           # k = n
+    ("random", 1, 1, 0.01),            # n = 1
+    # two blocks, the last partial, the row not a multiple of 128
+    ("ties", 2, (BLOCK_LINES + 88) * 128 + 5, 0.3),
+])
+def test_topk_select_matches_oracle(kind, c, n, frac):
+    """The kernel's idx, val and residual equal the oracle's bit for bit."""
+    x = _topk_rows(kind, c, n)
+    k = max(1, int(n * frac))
+    want = jax.jit(ref.topk_select, static_argnums=1)(x, k)
+    [got] = topk_select_rows((x,), (k,), interpret=True)
+    for name, g, w in zip(("idx", "val", "residual"), got, want):
+        np.testing.assert_array_equal(
+            np.asarray(g).view(np.int32), np.asarray(w).view(np.int32), err_msg=name
+        )
+
+
+def test_topk_select_one_row_surface():
+    """A 1-D row goes through the kernel as one client."""
+    x = _topk_rows("ties", 1, 3000)[0]
+    [got] = topk_select_rows((x,), (30,), interpret=True)
+    want = ref.topk_select(x, 30)
+    assert got[0].shape == (30,) and got[2].shape == (3000,)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g).view(np.int32), np.asarray(w).view(np.int32))
+
+
+def test_topk_select_rows_many_leaves_in_one_call():
+    """Leaves of mixed sizes and ks through one kernel call: each leaf's
+    payload and residual equal its own oracle's."""
+    ns = [1728, 64, 10, 36_864, 130, 5120]
+    fracs = [0.01, 1.0, 0.3, 0.01, 0.9, 0.02]
+    kinds = ["ties", "random", "special", "random", "equal", "clustered"]
+    xs = tuple(_topk_rows(kind, 3, n) for kind, n in zip(kinds, ns))
+    ks = tuple(max(1, int(n * f)) for n, f in zip(ns, fracs))
+    for x, k, got in zip(xs, ks, topk_select_rows(xs, ks, interpret=True)):
+        for g, w in zip(got, ref.topk_select(x, k)):
+            np.testing.assert_array_equal(np.asarray(g).view(np.int32), np.asarray(w).view(np.int32))
 
 
 def test_topk_codec_reduce_hits_scatter_kernel():
