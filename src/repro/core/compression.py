@@ -507,26 +507,28 @@ class UpdateCodec:
         segs = self.segment_map()
         leaves, treedef = jax.tree_util.tree_flatten(delta_tree)
         if segs.matches_leaves(leaves):
-            decs, rows = [], []
-            for leaf, row, seg in zip(leaves, state_row, segs):
-                dec, new_row = self.transmit_segment(
-                    leaf.astype(jnp.float32).reshape(-1), row, seg
-                )
-                decs.append(dec.reshape(leaf.shape).astype(leaf.dtype))
-                rows.append(new_row)
-            return jax.tree_util.tree_unflatten(treedef, decs), tuple(rows)
+            decs, rows = self.transmit_segments(
+                [leaf.astype(jnp.float32).reshape(-1) for leaf in leaves], state_row, segs
+            )
+            decs = [d.reshape(lf.shape).astype(lf.dtype) for d, lf in zip(decs, leaves)]
+            return jax.tree_util.tree_unflatten(treedef, decs), rows
 
         vec = tree_flatten_to_vector(delta_tree)
         self.segment_map(vec.shape[0])
-        decs, rows = [], []
-        for part, row, seg in zip(segs.split(vec), state_row, segs):
-            dec, new_row = self.transmit_segment(part, row, seg)
-            decs.append(dec.reshape(-1))
-            rows.append(new_row)
+        decs, rows = self.transmit_segments(segs.split(vec), state_row, segs)
         return (
-            tree_unflatten_from_vector(jnp.concatenate(decs), delta_tree),
-            tuple(rows),
+            tree_unflatten_from_vector(jnp.concatenate([d.reshape(-1) for d in decs]), delta_tree),
+            rows,
         )
+
+    def transmit_segments(self, vecs, state_row, segs: SegmentMap):
+        """One client's uplink for every segment: -> (decoded vecs, new
+        state tuple); by default each segment on its own
+        (``transmit_segment``)."""
+        out = [
+            self.transmit_segment(v, row, seg) for v, row, seg in zip(vecs, state_row, segs)
+        ]
+        return [d for d, _ in out], tuple(row for _, row in out)
 
     def transmit_segment(self, vec: jnp.ndarray, state_row, seg: Segment):
         """One client's uplink for ONE segment: (vec (seg.size,), row) ->
@@ -820,6 +822,18 @@ class TopKCodec(UpdateCodec):
         row zeroes the transmitted coordinates."""
         [(enc, new_row)] = self._encode_residual(vec + state_row)
         return self.decode_segment(enc, seg), new_row
+
+    def transmit_segments(self, vecs, state_row, segs: SegmentMap):
+        """The per-client path for every segment: one selection call."""
+        sent = self._encode_residual(*(v + row for v, row in zip(vecs, state_row)))
+        decs = [self.decode_segment(enc, seg) for (enc, _), seg in zip(sent, segs)]
+        return decs, tuple(row for _, row in sent)
+
+    def encode_structured(self, delta_vec: jnp.ndarray) -> StructuredUpdate:
+        """Per-segment payloads (protocol path), one selection call."""
+        segs = self.segment_map(int(delta_vec.shape[0]))
+        sent = self._encode_residual(*segs.split(delta_vec))
+        return StructuredUpdate(segs, tuple(enc for enc, _ in sent))
 
     def reduce(self, enc, weights: jnp.ndarray, *, interpret: bool = False):
         # sparse scatter-accumulate straight off the (idx, val) payload

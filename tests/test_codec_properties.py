@@ -315,19 +315,25 @@ def test_topk_selection_matches_stable_sort_oracle(case, rank):
         _assert_bitwise(mean, ops.topk_scatter_reduce(want_idx, want_val, w, n), "mean")
 
 
+_RESNET_LIKE = {
+    "stem": {"kernel": (3, 3, 3, 16), "scale": (16,), "bias": (16,)},
+    "block": {"conv1": (3, 3, 16, 32), "conv2": (3, 3, 32, 32), "scale": (32,)},
+    "head": {"kernel": (32, 10), "bias": (10,)},
+}
+
+
+def _resnet_like_tree(rng):
+    return jax.tree.map(
+        lambda s: jnp.asarray(rng.normal(size=s), jnp.float32), _RESNET_LIKE,
+        is_leaf=lambda s: isinstance(s, tuple),
+    )
+
+
 def test_topk_selection_segmented_tree_matches_oracle():
     """A ResNet-like tree, leaf by leaf through ``aggregate_updates``: new
     params and every residual row equal the stable-sort oracle's."""
     rng = np.random.default_rng(7)
-    shapes = {
-        "stem": {"kernel": (3, 3, 3, 16), "scale": (16,), "bias": (16,)},
-        "block": {"conv1": (3, 3, 16, 32), "conv2": (3, 3, 32, 32), "scale": (32,)},
-        "head": {"kernel": (32, 10), "bias": (10,)},
-    }
-    glob = jax.tree.map(
-        lambda s: jnp.asarray(rng.normal(size=s), jnp.float32), shapes,
-        is_leaf=lambda s: isinstance(s, tuple),
-    )
+    glob = _resnet_like_tree(rng)
     c = 4
     clients = jax.tree.map(
         lambda g: g + jnp.asarray(np.round(rng.normal(size=(c,) + g.shape) * 8) / 256, jnp.float32),
@@ -355,6 +361,47 @@ def test_topk_selection_segmented_tree_matches_oracle():
     for got, want in zip(new_state, want_state):
         _assert_bitwise(got, want, "residual")
     assert jax.tree.structure(new_glob) == treedef
+
+
+@pytest.mark.parametrize("surface", ["transmit_tree", "transmit_tree_flat", "encode_structured"])
+def test_topk_per_client_paths_select_every_segment_in_one_call(surface, monkeypatch):
+    """The per-client surfaces (mesh shard_map and sequential scan:
+    ``transmit_tree``, by leaves or by a flat vector's slices; the protocol:
+    ``encode_structured``) select every segment in one selection call, and
+    each segment's payload, decode and residual equal the stable-sort
+    oracle's bit for bit."""
+    rng = np.random.default_rng(11)
+    glob = _resnet_like_tree(rng)
+    codec = TopKCodec(frac=0.05, segments=SegmentMap.from_tree(glob))
+    delta = jax.tree.map(
+        lambda g: jnp.asarray(np.round(rng.normal(size=g.shape) * 8) / 256, jnp.float32), glob
+    )
+    vec = jnp.concatenate([leaf.reshape(-1) for leaf in jax.tree.leaves(delta)])
+    row = tuple(
+        jnp.asarray(rng.normal(size=seg.size) * 1e-3, jnp.float32) for seg in codec.segments
+    )
+    calls = []
+    select = ops.topk_select_rows
+    monkeypatch.setattr(ops, "topk_select_rows", lambda xs, ks: calls.append(ks) or select(xs, ks))
+
+    if surface == "encode_structured":
+        encs = codec.encode_structured(vec).payloads
+        effs = codec.segments.split(vec)
+    else:
+        tree = delta if surface == "transmit_tree" else vec
+        dec, new_row = codec.transmit_tree(tree, row)
+        decs = codec.segments.split(jnp.concatenate([d.reshape(-1) for d in jax.tree.leaves(dec)]))
+        effs = [part + r for part, r in zip(codec.segments.split(vec), row)]
+    assert len(calls) == 1 and len(calls[0]) == len(codec.segments)
+
+    for i, (seg, eff) in enumerate(zip(codec.segments, effs)):
+        idx, val = _oracle_encode(eff, codec.k_of(seg.size))
+        if surface == "encode_structured":
+            _assert_bitwise(encs[i]["idx"], idx, "idx")
+            _assert_bitwise(encs[i]["val"], val, "val")
+            continue
+        _assert_bitwise(decs[i], codec.decode({"idx": idx, "val": val, "n": seg.size}), "decode")
+        _assert_bitwise(new_row[i], eff.at[idx].set(0.0), "residual")
 
 
 @pytest.mark.parametrize("surface", ["encode_batch", "aggregate_batch"])
