@@ -39,6 +39,7 @@ from itertools import accumulate
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -181,22 +182,54 @@ def _pad_to(v, size, fill):
                    constant_values=fill)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "span"))
-def _leaf_lines(rows, k: int, span: int):
-    """One leaf's (C, n) rows -> its (C, span, 128) lines, threshold and
-    need (C,), and per line the equal keys and the kept before it (C,
-    span).  Jitted, so that leaves of one shape trace and lower once."""
-    c, n = rows.shape
-    x3 = _pad_to(rows, span * LANES, jnp.nan).reshape(c, span, LANES)
+def _line_tables(x3, leaf, nvalid, spans, ks):
+    """Every leaf's lines (C, L, 128), one after another -> each (client,
+    leaf) threshold and need (C, leaves), and per line the equal keys and
+    the kept before it in its leaf (C, L) and its first output slot (C, L).
+
+    The threshold is ``ref.topk_threshold``'s, for every leaf in the same
+    passes: each pass counts the keys of every ``STEP_LINES`` block against
+    its leaf's probes, and a running sum over the blocks gives each leaf's
+    counts (a block never straddles two leaves).  NaN pads key 0: they
+    reach no probe and are never above a threshold; equal to one of 0, they
+    are taken back out."""
+    c = x3.shape[0]
     key = ref.topk_keys(x3)
-    t = ref.topk_threshold(key, k)
-    valid = jnp.arange(span * LANES).reshape(span, LANES) < n
-    above = jnp.sum(valid & (key > t[:, None, None]), axis=2, dtype=jnp.int32)
-    at = jnp.sum(valid & (key == t[:, None, None]), axis=2, dtype=jnp.int32)
-    need = k - jnp.sum(above, axis=1, dtype=jnp.int32)
-    a = jnp.cumsum(at, axis=1, dtype=jnp.int32) - at
-    kept = above + jnp.clip(need[:, None] - a, 0, at)
-    return x3, t, need, a, jnp.cumsum(kept, axis=1, dtype=jnp.int32) - kept
+    k = np.asarray(ks, np.int32)
+    ends = np.cumsum(spans)  # each leaf's end line
+    blk_leaf = leaf[::STEP_LINES]
+
+    def leaf_sums(v):  # (C, blocks, ...) -> (C, leaves, ...)
+        run = jnp.cumsum(v, axis=1)[:, ends // STEP_LINES - 1]
+        return run - jnp.concatenate([jnp.zeros_like(run[:, :1]), run[:, :-1]], axis=1)
+
+    blocks = key.reshape(c, -1, STEP_LINES * LANES)
+
+    def settle(t, low, width):
+        tb = t[:, blk_leaf][:, :, None]
+        counts = jnp.stack([
+            jnp.sum(blocks >= tb + (j << low), axis=2, dtype=jnp.int32)
+            for j in range(1, 1 << width)
+        ], axis=-1)
+        reach = leaf_sums(counts) >= k[:, None]
+        return t + (jnp.sum(reach, axis=-1, dtype=jnp.int32) << low)
+
+    passes, top = divmod(31, ref.TOPK_PASS_BITS)
+    t = settle(jnp.zeros((c, len(ks)), jnp.int32), ref.TOPK_PASS_BITS * passes, top)
+    for i in reversed(range(passes)):
+        t = settle(t, ref.TOPK_PASS_BITS * i, ref.TOPK_PASS_BITS)
+
+    t_line = t[:, leaf]
+    above = jnp.sum(key > t_line[:, :, None], axis=2, dtype=jnp.int32)
+    at = jnp.sum(key == t_line[:, :, None], axis=2, dtype=jnp.int32)
+    at = at - jnp.where(t_line == 0, LANES - nvalid, 0)
+    need = k - leaf_sums(above.reshape(c, -1, STEP_LINES).sum(axis=2))
+    before = jnp.cumsum(at, axis=1) - at
+    a = before - before[:, ends - spans][:, leaf]
+    kept = above + jnp.clip(need[:, leaf] - a, 0, at)
+    # each leaf keeps exactly its k, so a running count over every line
+    # places the leaves' slots one after another
+    return t, need, a, jnp.cumsum(kept, axis=1) - kept
 
 
 @functools.partial(jax.jit, static_argnames=("ks", "interpret"))
@@ -219,26 +252,25 @@ def topk_select_rows(xs, ks, *, interpret: bool = False):
     bs = -(-bl // TILE) * TILE  # SMEM blocks of whole 1024s: XLA's layout of a long int32 vector
 
     # every leaf on whole steps of lines (NaN past its end: keys 0, masked)
-    leaves = [_leaf_lines(r, k, s) for r, k, s in zip(rows, ks, spans)]
-    x3, t, need = ([leaf[i] for leaf in leaves] for i in (0, 1, 2))
-    a = [leaf[3] for leaf in leaves]
-    p = [off + leaf[4] for off, leaf in zip(offs, leaves)]
+    x3 = jnp.concatenate([
+        _pad_to(r, s * LANES, jnp.nan).reshape(c, s, LANES) for r, s in zip(rows, spans)
+    ], axis=1)
+    leaf = np.repeat(np.arange(nl, dtype=np.int32), spans)
+    nvalid = np.concatenate([
+        np.clip(n - LANES * np.arange(s), 0, LANES) for n, s in zip(ns, spans)
+    ]).astype(np.int32)
+    t, need, a, p = _line_tables(x3, leaf, nvalid, spans, ks)
 
     # per-line tables, in SMEM blocks of ``bs`` a grid step; lines past the
     # last leaf's hold nothing and start at the last slot
-    leaf = jnp.concatenate([jnp.full(s, i, jnp.int32) for i, s in enumerate(spans)])
-    line = jnp.concatenate([
-        (jnp.arange(s, dtype=jnp.int32) << 8)
-        | jnp.clip(n - LANES * jnp.arange(s, dtype=jnp.int32), 0, LANES)
-        for n, s in zip(ns, spans)
-    ])
+    line = np.concatenate([np.arange(s) for s in spans]).astype(np.int32) << 8 | nvalid
 
     def blocks(v, fill):
         v = _pad_to(v, nb * bl, fill).reshape(v.shape[:-1] + (nb, bl))
         return _pad_to(v, bs, fill).reshape(-1)
 
-    def table(vs):  # (c, leaves) row-major, padded to whole 1024s
-        v = jnp.stack(vs, axis=1).reshape(-1)
+    def table(v):  # (c, leaves) row-major, padded to whole 1024s
+        v = v.reshape(-1)
         return _pad_to(v, -(-v.shape[0] // TILE) * TILE, 0)
 
     k_total = offs[-1]
@@ -265,11 +297,11 @@ def topk_select_rows(xs, ks, *, interpret: bool = False):
         interpret=interpret,
     )(
         table(t), table(need),
-        blocks(leaf[None], 0),
-        blocks(line[None], 0),
-        blocks(jnp.concatenate(a, axis=1), 0),
-        blocks(jnp.concatenate(p, axis=1), k_total),
-        jnp.concatenate(x3, axis=1),
+        blocks(jnp.asarray(leaf)[None], 0),
+        blocks(jnp.asarray(line)[None], 0),
+        blocks(a, 0),
+        blocks(p, k_total),
+        x3,
     )
     idx = idx.reshape(c, -1)
     val = jax.lax.bitcast_convert_type(val.reshape(c, -1), jnp.float32)
