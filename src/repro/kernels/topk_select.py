@@ -3,19 +3,21 @@ update, each client row's k kept coordinates in ascending order with their
 values, and the row with them zeroed (the error-feedback residual), all
 leaves in one kernel call and one pass over their rows.
 
-The selection is the oracle's (``ref.topk_select``): XLA finds each row's
-threshold ``t`` on the int32 keys of |x| (``ref.topk_keys``,
-``ref.topk_threshold``); every key above ``t`` is kept, and of the keys
-equal to it the first ``need = k - count(key > t)`` in index order.  The
-oracle then places the kept by a binary search for every output slot, k
-gathers a level, and a TPU gathers at ~15 ns an element.  Here each leaf's
-rows are laid out as lines of 128 lanes, the leaves one after another
-(each on whole steps of ``STEP_LINES`` lines), and:
+The selection is the oracle's (``ref.topk_select``): each row's threshold
+``t`` on the int32 keys of |x| (``ref.topk_keys``, ``ref.topk_threshold``);
+every key above ``t`` is kept, and of the keys equal to it the first
+``need = k - count(key > t)`` in index order.  The oracle then places the
+kept by a binary search for every output slot, k gathers a level, and a
+TPU gathers at ~15 ns an element.  Here each leaf's rows are laid out as
+lines of 128 lanes, the leaves one after another (each on whole steps of
+``STEP_LINES`` lines), and:
 
-1. XLA counts, per line, the keys above ``t`` and those equal to it, and
-   from the two counts each line's kept and two running totals within its
-   leaf: ``a``, the equal keys before the line, and ``p``, the output slot
-   of its first kept (the leaves' slots one after another).
+1. XLA finds every leaf's thresholds in the same counting passes over all
+   the lines (``_line_tables``: one fusion a pass for the whole update,
+   not one a leaf), then counts, per line, the keys above ``t`` and those
+   equal to it, and from the two counts each line's kept and two running
+   totals: ``a``, the equal keys before the line in its leaf, and ``p``,
+   the output slot of its first kept (the leaves' slots one after another).
 2. The kernel streams the lines in blocks.  In each step of lines it
    rebuilds the keys, keeps ``key > t`` and the equal keys whose rank
    (``a`` plus an in-line prefix count) is within ``need``, writes the
@@ -39,7 +41,6 @@ from itertools import accumulate
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -195,12 +196,17 @@ def _line_tables(x3, leaf, nvalid, spans, ks):
     are taken back out."""
     c = x3.shape[0]
     key = ref.topk_keys(x3)
-    k = np.asarray(ks, np.int32)
-    ends = np.cumsum(spans)  # each leaf's end line
-    blk_leaf = leaf[::STEP_LINES]
+    k = jnp.asarray(ks, jnp.int32)
+    ends = list(accumulate(spans))  # each leaf's end line
+    # index tables as arrays: indexing with a long list is slow to trace
+    blk_leaf = jnp.asarray(leaf[::STEP_LINES], jnp.int32)
+    blk_end = jnp.asarray([e // STEP_LINES - 1 for e in ends], jnp.int32)
+    first = jnp.asarray([e - s for e, s in zip(ends, spans)], jnp.int32)
+    pads = jnp.asarray([LANES - v for v in nvalid], jnp.int32)
+    leaf = jnp.asarray(leaf, jnp.int32)
 
     def leaf_sums(v):  # (C, blocks, ...) -> (C, leaves, ...)
-        run = jnp.cumsum(v, axis=1)[:, ends // STEP_LINES - 1]
+        run = jnp.cumsum(v, axis=1)[:, blk_end]
         return run - jnp.concatenate([jnp.zeros_like(run[:, :1]), run[:, :-1]], axis=1)
 
     blocks = key.reshape(c, -1, STEP_LINES * LANES)
@@ -222,10 +228,10 @@ def _line_tables(x3, leaf, nvalid, spans, ks):
     t_line = t[:, leaf]
     above = jnp.sum(key > t_line[:, :, None], axis=2, dtype=jnp.int32)
     at = jnp.sum(key == t_line[:, :, None], axis=2, dtype=jnp.int32)
-    at = at - jnp.where(t_line == 0, LANES - nvalid, 0)
+    at = at - jnp.where(t_line == 0, pads, 0)
     need = k - leaf_sums(above.reshape(c, -1, STEP_LINES).sum(axis=2))
     before = jnp.cumsum(at, axis=1) - at
-    a = before - before[:, ends - spans][:, leaf]
+    a = before - before[:, first][:, leaf]
     kept = above + jnp.clip(need[:, leaf] - a, 0, at)
     # each leaf keeps exactly its k, so a running count over every line
     # places the leaves' slots one after another
@@ -255,15 +261,14 @@ def topk_select_rows(xs, ks, *, interpret: bool = False):
     x3 = jnp.concatenate([
         _pad_to(r, s * LANES, jnp.nan).reshape(c, s, LANES) for r, s in zip(rows, spans)
     ], axis=1)
-    leaf = np.repeat(np.arange(nl, dtype=np.int32), spans)
-    nvalid = np.concatenate([
-        np.clip(n - LANES * np.arange(s), 0, LANES) for n, s in zip(ns, spans)
-    ]).astype(np.int32)
+    # per line (static): its leaf, its line within the leaf and valid lanes
+    leaf = [i for i, s in enumerate(spans) for _ in range(s)]
+    nvalid = [min(max(n - LANES * j, 0), LANES) for n, s in zip(ns, spans) for j in range(s)]
+    line = [j << 8 | v for j, v in zip((j for s in spans for j in range(s)), nvalid)]
     t, need, a, p = _line_tables(x3, leaf, nvalid, spans, ks)
 
     # per-line tables, in SMEM blocks of ``bs`` a grid step; lines past the
     # last leaf's hold nothing and start at the last slot
-    line = np.concatenate([np.arange(s) for s in spans]).astype(np.int32) << 8 | nvalid
 
     def blocks(v, fill):
         v = _pad_to(v, nb * bl, fill).reshape(v.shape[:-1] + (nb, bl))
@@ -297,8 +302,8 @@ def topk_select_rows(xs, ks, *, interpret: bool = False):
         interpret=interpret,
     )(
         table(t), table(need),
-        blocks(jnp.asarray(leaf)[None], 0),
-        blocks(jnp.asarray(line)[None], 0),
+        blocks(jnp.asarray(leaf, jnp.int32)[None], 0),
+        blocks(jnp.asarray(line, jnp.int32)[None], 0),
         blocks(a, 0),
         blocks(p, k_total),
         x3,
